@@ -9,7 +9,7 @@ export PYTHONPATH := src$(if $(PYTHONPATH),:$(PYTHONPATH))
 
 # tier-1 verify (ROADMAP.md)
 test:
-	$(PYTHON) -m pytest -x -q
+	JAX_PLATFORMS=cpu $(PYTHON) -m pytest -x -q
 
 # lint gate (ruff config in pyproject.toml). `ruff check` is repo-wide;
 # format parity is enforced on the sharded-runtime layer and grows

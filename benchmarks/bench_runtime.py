@@ -1115,6 +1115,9 @@ if __name__ == "__main__":
                    "zero episodes on a uniform control arm; writes "
                    "results/BENCH_selftune.json")
     args = p.parse_args()
+    from repro.compile_cache import enable as enable_compile_cache
+
+    enable_compile_cache()
     if args.slo:
         run_slo_gate(smoke=args.smoke,
                      scenario=args.scenario if args.scenario != "uniform"

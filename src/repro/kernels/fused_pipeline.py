@@ -11,14 +11,13 @@ stats plan — the paper's conditional compilation, now inside Pallas), and
 immediately runs the dense level-order forest traversal on the in-register
 feature tile. The feature matrix never touches HBM.
 
-Bit-parity with the unfused path is by construction, not luck:
-
-- feature columns come from the *same* emitter tracing the *same* static
-  plan, so the op graphs are identical;
-- the traversal unrolls tree blocks of `block_t` and accumulates
-  ``votes.sum(axis=1) / n_trees_padded`` per block in the same order as the
-  `tree_infer` kernel's grid reduction, with the same pass-through tree
-  padding and the same post-hoc vote-mean rescale.
+The contract is the float32 reference (`build_pipeline(use_kernel=False)`):
+equal predicted classes and probabilities within 1e-5. Feature columns
+come from the same emitter and plan as the XLA extraction, but a kernel
+body and an XLA program may round a reduction differently, so the
+two paths are not bitwise equal. The traversal is `tree_infer.forest_votes`,
+shared with the unfused kernel: exact table reads, votes summed tree by
+tree, then divided by the tree count.
 
 `fused_forest_infer` is the jit'd public entry; the packet tensors are
 donated (``donate_argnums``) so XLA can reuse their device buffers across
@@ -39,161 +38,119 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
+
+from repro.traffic.extraction import (
+    emit_agg_features,
+    emit_feature_columns,
+    emit_merged_columns,
+    pack_flags,
+)
+
+from .ops import resolve_interpret
+from .tree_infer import forest_votes, kernel_layout
 
 __all__ = ["fused_agg_infer", "fused_forest_infer", "fused_pipeline_call",
            "fused_multi_forest_infer", "fused_multi_forest_call",
            "stack_multi_forests"]
 
 
-def _traverse(x, feat, thr, leaf, *, forest_depth: int, n_trees: int,
-              block_t: int, rescale: float):
-    """Dense level-order forest traversal over an in-register feature tile.
+def _tile(i):
+    return (i, 0)
 
-    Shared by the window kernel and the aggregate kernel: bit-parity
-    between the two entries (and with `ops.forest_infer`) rests on both
-    tracing this exact block order, vote normalization, and rescale."""
-    bn = x.shape[0]
-    K = leaf.shape[2]
-    acc = jnp.zeros((bn, K), jnp.float32)
-    for j0 in range(0, n_trees, block_t):
-        fj = feat[j0:j0 + block_t]              # static slices: (bt, NI)
-        tj = thr[j0:j0 + block_t]
-        lj = leaf[j0:j0 + block_t]
-        bt = fj.shape[0]
-        node = jnp.zeros((bn, bt), jnp.int32)
-        for _ in range(forest_depth):
-            f = jnp.take_along_axis(
-                jnp.broadcast_to(fj[None], (bn, bt, fj.shape[1])),
-                node[:, :, None], axis=2,
-            )[..., 0]
-            th = jnp.take_along_axis(
-                jnp.broadcast_to(tj[None], (bn, bt, tj.shape[1])),
-                node[:, :, None], axis=2,
-            )[..., 0]
-            xv = jnp.take_along_axis(
-                jnp.broadcast_to(x[:, None, :], (bn, bt, x.shape[1])),
-                f.astype(jnp.int32)[:, :, None], axis=2,
-            )[..., 0]
-            node = 2 * node + 1 + (xv > th).astype(jnp.int32)
-        leaf_idx = node - (2 ** forest_depth - 1)
-        votes = jnp.take_along_axis(
-            jnp.broadcast_to(lj[None], (bn,) + lj.shape),
-            leaf_idx[:, :, None, None], axis=2,
-        )[:, :, 0, :]                           # (bn, bt, K)
-        acc = acc + votes.sum(axis=1) / n_trees
-    return acc * rescale
+
+def _whole3(i):
+    return (0, 0, 0)
+
+
+def _pad_rows(rem_n, *arrays):
+    """Zero-pad the flow axis of every array by `rem_n` rows (padding rows
+    have flow_len 0, so every mask is empty)."""
+    return [jnp.pad(a, ((0, rem_n),) + ((0, 0),) * (a.ndim - 1))
+            for a in arrays]
+
+
+def _packet_specs(bn, P):
+    """BlockSpecs of the six (N, P) packet tensors and the meta tile."""
+    return [pl.BlockSpec((bn, P), _tile) for _ in range(6)] + [
+        pl.BlockSpec((bn, 4), _tile)]
+
+
+def _forest_specs(feature, leaf):
+    """BlockSpecs that keep a whole `kernel_layout` forest resident."""
+    return [pl.BlockSpec(feature.shape, _whole3),
+            pl.BlockSpec(feature.shape, _whole3),
+            pl.BlockSpec(leaf.shape, _whole3)]
 
 
 def _fused_kernel(
     ts_ref, size_ref, dir_ref, ttl_ref, win_ref, flags_ref, meta_ref,
     f_ref, t_ref, l_ref, o_ref,
-    *, plan, depth: int, forest_depth: int, n_trees: int, block_t: int,
-    rescale: float,
+    *, plan, depth: int, forest_depth: int,
 ):
-    from repro.traffic.extraction import emit_feature_columns
-
-    ts = ts_ref[...]            # (bn, P) float32
     meta = meta_ref[...]        # (bn, 4) float32: flow_len, proto, s/d_port
     cols = emit_feature_columns(
         plan,
-        ts=ts, size=size_ref[...], direction=dir_ref[...], ttl=ttl_ref[...],
-        winsize=win_ref[...], flags=flags_ref[...], flow_len=meta[:, 0],
-        proto=meta[:, 1], s_port=meta[:, 2], d_port=meta[:, 3], depth=depth,
+        ts=ts_ref[...], size=size_ref[...], direction=dir_ref[...],
+        ttl=ttl_ref[...], winsize=win_ref[...], flags=flags_ref[...],
+        flow_len=meta[:, 0], proto=meta[:, 1], s_port=meta[:, 2],
+        d_port=meta[:, 3], depth=depth,
     )
     x = jnp.stack(cols, axis=1)                 # (bn, F) — in VMEM only
-    o_ref[...] = _traverse(
-        x, f_ref[...], t_ref[...], l_ref[...],
-        forest_depth=forest_depth, n_trees=n_trees, block_t=block_t,
-        rescale=rescale,
-    )
+    n = f_ref.shape[0]
+    o_ref[...] = forest_votes(x, f_ref, t_ref, l_ref, t0=0, n_trees=n,
+                              depth=forest_depth) / n
 
 
 def _agg_kernel(
     agg_ref, meta_ref, f_ref, t_ref, l_ref, o_ref,
-    *, plan, forest_depth: int, n_trees: int, block_t: int, rescale: float,
+    *, plan, forest_depth: int,
 ):
     """Incremental entry (DESIGN.md §12): feature columns from the compact
     per-flow aggregate block instead of the raw packet window — a
     ``(bn, AGG_WIDTH)`` tile replaces six ``(bn, P[, 8])`` packet tensors,
     so a refresh batch moves ~53 floats per flow regardless of how long
     the flow has lived."""
-    from repro.traffic.extraction import emit_agg_features
-
-    agg = agg_ref[...]          # (bn, AGG_WIDTH) float32
     meta = meta_ref[...]        # (bn, 3) float32: proto, s_port, d_port
     cols = emit_agg_features(
-        plan, agg, proto=meta[:, 0], s_port=meta[:, 1], d_port=meta[:, 2])
+        plan, agg_ref[...], proto=meta[:, 0], s_port=meta[:, 1],
+        d_port=meta[:, 2])
     x = jnp.stack(cols, axis=1)
-    o_ref[...] = _traverse(
-        x, f_ref[...], t_ref[...], l_ref[...],
-        forest_depth=forest_depth, n_trees=n_trees, block_t=block_t,
-        rescale=rescale,
-    )
+    n = f_ref.shape[0]
+    o_ref[...] = forest_votes(x, f_ref, t_ref, l_ref, t0=0, n_trees=n,
+                              depth=forest_depth) / n
 
 
 def fused_pipeline_call(
     ts, size, direction, ttl, winsize, flags, meta,
     feature, threshold, leaf,
     *, plan, depth: int, forest_depth: int,
-    block_n: int = 256, block_t: int = 8, interpret: bool = False,
+    block_n: int = 256, interpret: bool = False,
 ):
     """Raw pallas_call: one launch over flow tiles, features never hit HBM.
 
-    Expects float32 packet tensors, int32 `direction`, float32 `flags`
-    ``(N, P, 8)``, and ``meta = [flow_len, proto, s_port, d_port]`` as
-    ``(N, 4)`` float32. Pads the flow axis to the block multiple (padding
-    rows have flow_len 0: every mask is empty) and the tree axis with
-    pass-through trees, mirroring `ops.forest_infer` exactly.
+    Expects float32 packet tensors, int32 `direction`, `flags` as the
+    ``(N, P)`` int32 `pack_flags` bit mask, and
+    ``meta = [flow_len, proto, s_port, d_port]`` as
+    ``(N, 4)`` float32. Pads the flow axis to the block multiple; the
+    whole forest stays resident, so the tree axis needs no padding.
     """
     N, P = ts.shape
-    T, NI = feature.shape
-    NL, K = leaf.shape[1], leaf.shape[2]
+    K = leaf.shape[2]
     bn = min(block_n, N)
-    bt = min(block_t, T)
-
     rem_n = (-N) % bn
     if rem_n:
-        def pad2(a):
-            return jnp.pad(a, ((0, rem_n), (0, 0)))
-
-        ts, size, direction, ttl, winsize, meta = map(
-            pad2, (ts, size, direction, ttl, winsize, meta))
-        flags = jnp.pad(flags, ((0, rem_n), (0, 0), (0, 0)))
-    # same pass-through padding + rescale recipe as the unfused tree kernel
-    # (shared helper: the bit-parity contract depends on it)
-    from .tree_infer import pad_forest_blocks
-
-    feature, threshold, leaf, rem_t = pad_forest_blocks(
-        feature, threshold, leaf, bt)
-    rescale = (T + rem_t) / T if rem_t else 1.0
-
+        ts, size, direction, ttl, winsize, flags, meta = _pad_rows(
+            rem_n, ts, size, direction, ttl, winsize, flags, meta)
+    feature, threshold, leaf = kernel_layout(feature, threshold, leaf)
     kern = functools.partial(
-        _fused_kernel, plan=plan, depth=depth, forest_depth=forest_depth,
-        n_trees=T + rem_t, block_t=bt, rescale=rescale,
-    )
-    def tile(i):
-        return (i, 0)
-
-    def whole(i):
-        return (0, 0)
-
+        _fused_kernel, plan=plan, depth=depth, forest_depth=forest_depth)
     out = pl.pallas_call(
         kern,
         grid=((N + rem_n) // bn,),
-        in_specs=[
-            pl.BlockSpec((bn, P), tile),            # ts
-            pl.BlockSpec((bn, P), tile),            # size
-            pl.BlockSpec((bn, P), tile),            # direction
-            pl.BlockSpec((bn, P), tile),            # ttl
-            pl.BlockSpec((bn, P), tile),            # winsize
-            pl.BlockSpec((bn, P, 8), lambda i: (i, 0, 0)),  # flags
-            pl.BlockSpec((bn, 4), tile),            # meta
-            pl.BlockSpec((T + rem_t, NI), whole),   # forest: resident
-            pl.BlockSpec((T + rem_t, NI), whole),
-            pl.BlockSpec((T + rem_t, NL, K), lambda i: (0, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((bn, K), tile),
+        in_specs=_packet_specs(bn, P) + _forest_specs(feature, leaf),
+        out_specs=pl.BlockSpec((bn, K), _tile),
         out_shape=jax.ShapeDtypeStruct((N + rem_n, K), jnp.float32),
         interpret=interpret,
     )(ts, size, direction, ttl, winsize, flags, meta, feature, threshold, leaf)
@@ -202,8 +159,7 @@ def fused_pipeline_call(
 
 @functools.partial(
     jax.jit,
-    static_argnames=("plan", "depth", "forest_depth", "block_n", "block_t",
-                     "interpret"),
+    static_argnames=("plan", "depth", "forest_depth", "block_n", "interpret"),
     donate_argnums=(0, 1, 2, 3, 4, 5),
 )
 def fused_forest_infer(
@@ -211,27 +167,25 @@ def fused_forest_infer(
     flow_len, proto, s_port, d_port,
     feature, threshold, leaf,
     *, plan, depth: int, forest_depth: int,
-    block_n: int = 256, block_t: int = 8, interpret: bool | None = None,
+    block_n: int = 256, interpret: bool | None = None,
 ):
     """Jit'd fused pipeline entry: packets -> class probabilities, one launch.
 
     The packet tensors (args 0-5) are donated: each micro-batch's device
     buffers are released back to XLA as soon as the launch consumes them,
     so steady-state serving reuses a fixed set of device allocations.
-    Accepts uint8 `direction`/`flags` (converted on device, keeping the
-    host staging arena copy-free); `plan` comes from
+    Accepts uint8 `direction` and ``(N, P, 8)`` uint8 or float32 `flags`
+    (converted and bit-packed on device, keeping the host staging arena
+    copy-free); `plan` comes from
     `repro.traffic.extraction.stats_plan`.
     """
-    if interpret is None:
-        from .ops import default_interpret
-        interpret = default_interpret()
     meta = jnp.stack(
         [flow_len.astype(jnp.float32), proto, s_port, d_port], axis=1)
     return fused_pipeline_call(
         ts, size, direction.astype(jnp.float32), ttl, winsize,
-        flags.astype(jnp.float32), meta, feature, threshold, leaf,
+        pack_flags(flags), meta, feature, threshold, leaf,
         plan=plan, depth=depth, forest_depth=forest_depth,
-        block_n=block_n, block_t=block_t, interpret=interpret,
+        block_n=block_n, interpret=resolve_interpret(interpret),
     )
 
 
@@ -242,50 +196,43 @@ def fused_forest_infer(
 # feature columns are computed once over the in-VMEM packet tile, then each
 # tenant's forest — stacked along the tree axis with a static offset, its
 # node feature ids pre-remapped into merged-column space — traverses the
-# same feature tile via the exact solo `_traverse`, emitting its own
-# prediction lanes into a per-tenant slice of the output. Bit-parity with N
-# solo launches holds tenant by tenant: the merged emitter slices the
-# window to each tenant's depth before reducing, the remapped gather reads
-# the same feature values, and the per-tenant block order / vote
-# normalization / rescale are the solo recipe verbatim.
+# same feature tile via the solo `forest_votes`. Leaf tables are stacked at
+# the widest tenant's class count, so the resident forest grows with the
+# trees and not with trees x tenants; a one-hot matmul moves each tenant's
+# vote sums to its own lane span of one accumulator, with no lane-sliced
+# store, and tenant t's lanes hold exactly the sum a solo launch computes.
 
 
-def stack_multi_forests(forests, tenant_cols, *, block_t: int = 8):
+def stack_multi_forests(forests, tenant_cols):
     """Stack N tenants' forests into tenant-stacked node arrays.
 
-    Each forest is padded with pass-through trees to its own solo block
-    multiple (`pad_forest_blocks` — same recipe, same rescale, so the
-    per-tenant accumulation order matches a solo launch bit for bit),
-    its node feature ids are remapped through `tenant_cols[t]` into
-    merged-column space, and node/leaf/class axes are zero-padded to the
-    fleet maxima (statically sliced off inside the kernel). Returns
-    ``(feature, threshold, leaf, tenants)`` where ``tenants`` is the
-    static per-tenant spec tuple
-    ``(offset, n_padded, forest_depth, block_t, n_internal, n_leaf,
-    n_out, rescale)`` that the kernel specializes on.
+    Each forest's node feature ids are remapped through `tenant_cols[t]`
+    into merged-column space and its node axis is zero-padded to the fleet
+    maximum (padding nodes are never visited). Leaf tables are padded to
+    ``(T_t, NL_max, K_max)`` with tenant t's classes at lanes ``[0, K_t)``.
+    Returns ``(feature, threshold, leaf, tenants)`` where ``tenants`` is
+    the static per-tenant spec tuple
+    ``(offset, n_trees, forest_depth, k0, k0 + K_t)`` that the kernel
+    specializes on: tenant t's probabilities are output lanes
+    ``[k0, k0 + K_t)``.
     """
-    from .tree_infer import pad_forest_blocks
-
     ni_max = max(int(f.feature.shape[1]) for f in forests)
     nl_max = max(int(f.leaf.shape[1]) for f in forests)
     k_max = max(int(f.leaf.shape[2]) for f in forests)
     feats, thrs, leafs, tenants = [], [], [], []
-    off = 0
+    off = k0 = 0
     for f, cols in zip(forests, tenant_cols):
         T, ni = f.feature.shape
         nl, k = f.leaf.shape[1], f.leaf.shape[2]
-        bt = min(block_t, int(T))
         remap = jnp.asarray(cols, jnp.int32)[jnp.asarray(f.feature, jnp.int32)]
-        feat, thr, leaf, rem_t = pad_forest_blocks(
-            remap, jnp.asarray(f.threshold), jnp.asarray(f.leaf), bt)
-        tp = int(T) + rem_t
-        feats.append(jnp.pad(feat, ((0, 0), (0, ni_max - ni))))
-        thrs.append(jnp.pad(thr, ((0, 0), (0, ni_max - ni))))
-        leafs.append(jnp.pad(
-            leaf, ((0, 0), (0, nl_max - nl), (0, k_max - k))))
-        tenants.append((off, tp, int(f.depth), bt, int(ni), int(nl), int(k),
-                        (tp / T) if rem_t else 1.0))
-        off += tp
+        feats.append(jnp.pad(remap, ((0, 0), (0, ni_max - ni))))
+        thrs.append(jnp.pad(jnp.asarray(f.threshold, jnp.float32),
+                            ((0, 0), (0, ni_max - ni))))
+        leafs.append(jnp.pad(jnp.asarray(f.leaf, jnp.float32),
+                             ((0, 0), (0, nl_max - nl), (0, k_max - k))))
+        tenants.append((off, int(T), int(f.depth), k0, k0 + int(k)))
+        off += int(T)
+        k0 += int(k)
     return (jnp.concatenate(feats, axis=0), jnp.concatenate(thrs, axis=0),
             jnp.concatenate(leafs, axis=0), tuple(tenants))
 
@@ -295,25 +242,33 @@ def _multi_kernel(
     f_ref, t_ref, l_ref, o_ref,
     *, merged, tenants,
 ):
-    from repro.traffic.extraction import emit_merged_columns
-
-    ts = ts_ref[...]            # (bn, P) float32
     meta = meta_ref[...]        # (bn, 4) float32: flow_len, proto, s/d_port
     cols = emit_merged_columns(
         merged,
-        ts=ts, size=size_ref[...], direction=dir_ref[...], ttl=ttl_ref[...],
-        winsize=win_ref[...], flags=flags_ref[...], flow_len=meta[:, 0],
-        proto=meta[:, 1], s_port=meta[:, 2], d_port=meta[:, 3],
+        ts=ts_ref[...], size=size_ref[...], direction=dir_ref[...],
+        ttl=ttl_ref[...], winsize=win_ref[...], flags=flags_ref[...],
+        flow_len=meta[:, 0], proto=meta[:, 1], s_port=meta[:, 2],
+        d_port=meta[:, 3],
     )
     x = jnp.stack(cols, axis=1)                 # (bn, F_union) — VMEM only
-    k0 = 0
-    for off, tp, fd, bt, ni, nl, k, rescale in tenants:
-        o_ref[:, k0:k0 + k] = _traverse(
-            x, f_ref[off:off + tp, :ni], t_ref[off:off + tp, :ni],
-            l_ref[off:off + tp, :nl, :k],
-            forest_depth=fd, n_trees=tp, block_t=bt, rescale=rescale,
-        )
-        k0 += k
+    bn, k_sum = o_ref.shape
+    k_max = l_ref.shape[1]
+    acc = jnp.zeros((bn, k_sum), jnp.float32)
+    row = lax.broadcasted_iota(jnp.int32, (k_max, k_sum), 0)
+    col = lax.broadcasted_iota(jnp.int32, (k_max, k_sum), 1)
+    lane = lax.broadcasted_iota(jnp.int32, (1, k_sum), 1)
+    n_trees = jnp.ones((1, k_sum), jnp.float32)
+    for off, n, fd, k0, k1 in tenants:
+        votes = forest_votes(x, f_ref, t_ref, l_ref, t0=off, n_trees=n,
+                             depth=fd)
+        # one-hot (k_max, k_sum): vote lane k -> output lane k0 + k; exact
+        # at HIGHEST (each output is one f32 vote sum plus zeros)
+        place = (col == row + k0) & (row < k1 - k0)
+        acc = acc + jnp.dot(votes, place.astype(jnp.float32),
+                            precision=lax.Precision.HIGHEST,
+                            preferred_element_type=jnp.float32)
+        n_trees = jnp.where((lane >= k0) & (lane < k1), float(n), n_trees)
+    o_ref[...] = acc / n_trees
 
 
 def fused_multi_forest_call(
@@ -325,50 +280,24 @@ def fused_multi_forest_call(
     """Raw pallas_call: one launch, N tenants' prediction lanes.
 
     `feature`/`threshold`/`leaf` are the tenant-stacked arrays from
-    `stack_multi_forests` (already tree-padded and remapped — no further
-    padding here); the output is ``(N, sum of per-tenant n_out)`` with
-    tenant t's probabilities in its contiguous lane slice. Flow-axis
+    `stack_multi_forests`; the output is ``(N, sum of per-tenant n_out)``
+    with tenant t's probabilities in its contiguous lane slice. Flow-axis
     padding matches `fused_pipeline_call` (zero rows: every mask empty).
     """
     N, P = ts.shape
-    TP, NI = feature.shape
-    NL, K = leaf.shape[1], leaf.shape[2]
-    k_sum = sum(t[6] for t in tenants)
+    k_sum = tenants[-1][4]
     bn = min(block_n, N)
-
     rem_n = (-N) % bn
     if rem_n:
-        def pad2(a):
-            return jnp.pad(a, ((0, rem_n), (0, 0)))
-
-        ts, size, direction, ttl, winsize, meta = map(
-            pad2, (ts, size, direction, ttl, winsize, meta))
-        flags = jnp.pad(flags, ((0, rem_n), (0, 0), (0, 0)))
-
+        ts, size, direction, ttl, winsize, flags, meta = _pad_rows(
+            rem_n, ts, size, direction, ttl, winsize, flags, meta)
+    feature, threshold, leaf = kernel_layout(feature, threshold, leaf)
     kern = functools.partial(_multi_kernel, merged=merged, tenants=tenants)
-
-    def tile(i):
-        return (i, 0)
-
-    def whole(i):
-        return (0, 0)
-
     out = pl.pallas_call(
         kern,
         grid=((N + rem_n) // bn,),
-        in_specs=[
-            pl.BlockSpec((bn, P), tile),            # ts
-            pl.BlockSpec((bn, P), tile),            # size
-            pl.BlockSpec((bn, P), tile),            # direction
-            pl.BlockSpec((bn, P), tile),            # ttl
-            pl.BlockSpec((bn, P), tile),            # winsize
-            pl.BlockSpec((bn, P, 8), lambda i: (i, 0, 0)),  # flags
-            pl.BlockSpec((bn, 4), tile),            # meta
-            pl.BlockSpec((TP, NI), whole),          # stacked forest: resident
-            pl.BlockSpec((TP, NI), whole),
-            pl.BlockSpec((TP, NL, K), lambda i: (0, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((bn, k_sum), tile),
+        in_specs=_packet_specs(bn, P) + _forest_specs(feature, leaf),
+        out_specs=pl.BlockSpec((bn, k_sum), _tile),
         out_shape=jax.ShapeDtypeStruct((N + rem_n, k_sum), jnp.float32),
         interpret=interpret,
     )(ts, size, direction, ttl, winsize, flags, meta, feature, threshold, leaf)
@@ -392,67 +321,43 @@ def fused_multi_forest_infer(
     `fused_forest_infer`; the jit cache keys on the static
     ``(merged, tenants, batch shape)`` tuple, so a multi-tenant bundle
     hot-swap coexists with whatever it replaces (DESIGN.md §9.3)."""
-    if interpret is None:
-        from .ops import default_interpret
-        interpret = default_interpret()
     meta = jnp.stack(
         [flow_len.astype(jnp.float32), proto, s_port, d_port], axis=1)
     return fused_multi_forest_call(
         ts, size, direction.astype(jnp.float32), ttl, winsize,
-        flags.astype(jnp.float32), meta, feature, threshold, leaf,
-        merged=merged, tenants=tenants, block_n=block_n, interpret=interpret,
+        pack_flags(flags), meta, feature, threshold, leaf,
+        merged=merged, tenants=tenants, block_n=block_n,
+        interpret=resolve_interpret(interpret),
     )
 
 
 def fused_agg_call(
     agg, meta, feature, threshold, leaf,
     *, plan, forest_depth: int,
-    block_n: int = 256, block_t: int = 8, interpret: bool = False,
+    block_n: int = 256, interpret: bool = False,
 ):
     """Raw pallas_call for the aggregate entry: one launch over flow tiles
     of the compact ``(N, AGG_WIDTH)`` running-statistic block. Pads the
     flow axis with all-zero rows (a zero aggregate has every count at 0,
     so the emitter's masked reductions yield a defined all-zero feature
-    row) and the tree axis with pass-through trees, exactly as the window
-    entry does."""
+    row), exactly as the window entry does."""
     N, W = agg.shape
-    T, NI = feature.shape
-    NL, K = leaf.shape[1], leaf.shape[2]
+    K = leaf.shape[2]
     bn = min(block_n, N)
-    bt = min(block_t, T)
-
     rem_n = (-N) % bn
     if rem_n:
-        agg = jnp.pad(agg, ((0, rem_n), (0, 0)))
-        meta = jnp.pad(meta, ((0, rem_n), (0, 0)))
-    from .tree_infer import pad_forest_blocks
-
-    feature, threshold, leaf, rem_t = pad_forest_blocks(
-        feature, threshold, leaf, bt)
-    rescale = (T + rem_t) / T if rem_t else 1.0
-
-    kern = functools.partial(
-        _agg_kernel, plan=plan, forest_depth=forest_depth,
-        n_trees=T + rem_t, block_t=bt, rescale=rescale,
-    )
-
-    def tile(i):
-        return (i, 0)
-
-    def whole(i):
-        return (0, 0)
-
+        agg, meta = _pad_rows(rem_n, agg, meta)
+    feature, threshold, leaf = kernel_layout(feature, threshold, leaf)
+    kern = functools.partial(_agg_kernel, plan=plan,
+                             forest_depth=forest_depth)
     out = pl.pallas_call(
         kern,
         grid=((N + rem_n) // bn,),
         in_specs=[
-            pl.BlockSpec((bn, W), tile),            # aggregate block
-            pl.BlockSpec((bn, 3), tile),            # proto, s_port, d_port
-            pl.BlockSpec((T + rem_t, NI), whole),   # forest: resident
-            pl.BlockSpec((T + rem_t, NI), whole),
-            pl.BlockSpec((T + rem_t, NL, K), lambda i: (0, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((bn, K), tile),
+            pl.BlockSpec((bn, W), _tile),           # aggregate block
+            pl.BlockSpec((bn, 3), _tile),           # proto, s_port, d_port
+        ] + _forest_specs(feature, leaf),
+        out_specs=pl.BlockSpec((bn, K), _tile),
         out_shape=jax.ShapeDtypeStruct((N + rem_n, K), jnp.float32),
         interpret=interpret,
     )(agg, meta, feature, threshold, leaf)
@@ -461,26 +366,22 @@ def fused_agg_call(
 
 @functools.partial(
     jax.jit,
-    static_argnames=("plan", "forest_depth", "block_n", "block_t",
-                     "interpret"),
+    static_argnames=("plan", "forest_depth", "block_n", "interpret"),
 )
 def fused_agg_infer(
     agg, proto, s_port, d_port,
     feature, threshold, leaf,
     *, plan, forest_depth: int,
-    block_n: int = 256, block_t: int = 8, interpret: bool | None = None,
+    block_n: int = 256, interpret: bool | None = None,
 ):
     """Jit'd incremental pipeline entry: aggregate rows -> class
     probabilities, one launch. The refresh path is low-rate (one batch per
     `refresh_every` packets of frozen traffic), so inputs are not donated:
     the host-side staging block is reused synchronously by the dispatcher.
     """
-    if interpret is None:
-        from .ops import default_interpret
-        interpret = default_interpret()
     meta = jnp.stack([proto, s_port, d_port], axis=1)
     return fused_agg_call(
         agg.astype(jnp.float32), meta, feature, threshold, leaf,
         plan=plan, forest_depth=forest_depth,
-        block_n=block_n, block_t=block_t, interpret=interpret,
+        block_n=block_n, interpret=resolve_interpret(interpret),
     )

@@ -1,10 +1,10 @@
 """Public jit'd wrappers for the Pallas kernels.
 
 Each wrapper pads inputs to block multiples, dispatches the kernel, and
-slices the result. `interpret` defaults to auto: Pallas interpret mode on
-CPU (this container), compiled Mosaic on real TPUs. Pure-jnp fallbacks
-(`use_kernel=False`) route to the ref implementations — the dry-run can
-lower either path.
+slices the result. `interpret` defaults to auto (`resolve_interpret`):
+Pallas interpret mode on the CPU backend, compiled Mosaic on a TPU; any
+other backend is an error, never a silent interpret. The pure-jnp oracles
+(`use_kernel=False`) are the ref implementations.
 """
 from __future__ import annotations
 
@@ -20,7 +20,7 @@ from .mamba_scan import mamba_scan_kernel_call
 from .tree_infer import forest_infer_kernel_call
 
 __all__ = [
-    "default_interpret",
+    "resolve_interpret",
     "flash_attention",
     "decode_attention",
     "forest_infer",
@@ -29,8 +29,27 @@ __all__ = [
 ]
 
 
-def default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+def resolve_interpret(interpret: bool | None = None) -> bool:
+    """Whether a Pallas kernel runs in interpret mode on this backend.
+
+    ``None`` picks interpret mode on the CPU backend and Mosaic on a TPU.
+    Interpret mode on any backend but the CPU raises, and so does ``None``
+    on a backend that is neither: a served kernel never falls back to the
+    interpreter on an accelerator. ``False`` is always honoured, so a
+    kernel can be compiled for a described TPU from a CPU process.
+    """
+    backend = jax.default_backend()
+    if interpret is None:
+        if backend not in ("cpu", "tpu"):
+            raise RuntimeError(
+                f"no Pallas kernel path for backend {backend!r}: kernels "
+                "compile with Mosaic on a TPU and interpret only on the CPU")
+        interpret = backend == "cpu"
+    if interpret and backend != "cpu":
+        raise RuntimeError(
+            f"Pallas interpret mode requested on backend {backend!r}; "
+            "interpret mode runs only on the CPU backend")
+    return interpret
 
 
 def _pad_to(x: jax.Array, axis: int, mult: int, value=0.0):
@@ -49,7 +68,7 @@ def _pad_to(x: jax.Array, axis: int, mult: int, value=0.0):
 def flash_attention(
     q, k, v, *, causal=True, scale=None, block_q=128, block_k=128, interpret=None
 ):
-    interpret = default_interpret() if interpret is None else interpret
+    interpret = resolve_interpret(interpret)
     Tq, Tk = q.shape[2], k.shape[2]
     bq = min(block_q, Tq)
     bk = min(block_k, Tk)
@@ -72,7 +91,7 @@ def flash_attention(
 @functools.partial(jax.jit, static_argnames=("scale", "block_s", "interpret"))
 def decode_attention(q, k_cache, v_cache, lengths, *, scale=None, block_s=256,
                      interpret=None):
-    interpret = default_interpret() if interpret is None else interpret
+    interpret = resolve_interpret(interpret)
     S = k_cache.shape[1]
     bs = min(block_s, S)
     k_p, _ = _pad_to(k_cache, 1, bs)
@@ -86,10 +105,8 @@ def decode_attention(q, k_cache, v_cache, lengths, *, scale=None, block_s=256,
 @functools.partial(jax.jit, static_argnames=("depth", "block_n", "block_t", "interpret"))
 def forest_infer(x, feature, threshold, leaf, depth, *, block_n=256, block_t=8,
                  interpret=None):
-    # flow/tree padding, pass-through trees, and the vote-mean rescale all
-    # live in the kernel call (shared with the fused pipeline via
-    # tree_infer.pad_forest_blocks — the bit-parity contract)
-    interpret = default_interpret() if interpret is None else interpret
+    # flow/tree padding and pass-through trees live in the kernel call
+    interpret = resolve_interpret(interpret)
     return forest_infer_kernel_call(
         x, feature, threshold, leaf, depth,
         block_n=block_n, block_t=block_t, interpret=interpret,
@@ -98,14 +115,14 @@ def forest_infer(x, feature, threshold, leaf, depth, *, block_n=256, block_t=8,
 
 @functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
 def flow_stats(values, mask, *, block_n=512, interpret=None):
-    interpret = default_interpret() if interpret is None else interpret
+    interpret = resolve_interpret(interpret)
     return flow_stats_kernel_call(
         values, mask, block_n=block_n, interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def mamba_scan(x, dt, A, Bm, Cm, *, chunk=128, interpret=None):
-    interpret = default_interpret() if interpret is None else interpret
+    interpret = resolve_interpret(interpret)
     T = x.shape[1]
     c = min(chunk, T)
     if T % c:

@@ -7,10 +7,16 @@ index arithmetic, unrolled over the (static) depth:
 
     node <- 2*node + 1 + (x[feat[node]] > thresh[node])
 
+Mosaic lowers no gather over a vector, so `forest_votes` reads every
+table by compare/select instead (one-hot over the node or feature axis,
+then a lane reduction) and reads leaf votes with a one-hot × leaf-table
+matmul. Trees run in a `lax.fori_loop`, so compile time does not grow
+with the forest. `forest_votes` is the one traversal: this kernel and the
+three fused kernels (`repro.kernels.fused_pipeline`) all call it.
+
 The grid tiles (flow_block × tree_block); each step keeps a (bn, F) tile of
-flows and a tree block's node/leaf tables in VMEM, updates a (bn, bt) vector
-of node cursors per level with VREG gathers, and accumulates class votes
-into the output tile across tree blocks (the output block index only
+flows and a tree block's node/leaf tables in VMEM and accumulates the vote
+sum into the output tile across tree blocks (the output block index only
 depends on the flow axis, so Pallas keeps it resident while the tree axis
 iterates — a reduction without HBM round-trips).
 """
@@ -20,19 +26,33 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
 
-__all__ = ["forest_infer_kernel_call", "pad_forest_blocks"]
+__all__ = ["forest_infer_kernel_call", "forest_votes", "kernel_layout",
+           "pad_forest_blocks"]
+
+
+def kernel_layout(feature, threshold, leaf):
+    """Dense forest arrays -> the layout `forest_votes` reads.
+
+    Node tables go from ``(T, NI)`` to ``(T, 1, NI)``, so a tree's row is
+    read with a dynamic index on the leading axis (Mosaic refuses a
+    dynamic unaligned sublane index under a lane window). Leaves go from
+    ``(T, NL, K)`` to ``(T, K, NL)``, which puts the long leaf axis on the
+    lanes instead of padding a few classes to 128 lanes in VMEM.
+    """
+    T = feature.shape[0]
+    return (feature.reshape(T, 1, -1), threshold.reshape(T, 1, -1),
+            jnp.swapaxes(leaf, 1, 2))
 
 
 def pad_forest_blocks(feature, threshold, leaf, block_t: int):
     """Pad the tree axis to a `block_t` multiple with pass-through trees.
 
     Padding trees have +inf thresholds (every comparison goes left) and
-    all-zero leaves, so they contribute nothing to the vote sum; callers
-    divide by the padded count and rescale by ``(T + rem) / T`` afterwards.
-    The single source of this recipe: `forest_infer_kernel_call` and the
-    fused pipeline kernel must pad identically or their bit-parity breaks.
+    all-zero leaves, so they add exactly nothing to the vote sum; the
+    caller divides that sum by the true tree count.
     Returns ``(feature, threshold, leaf, rem_t)``.
     """
     T = feature.shape[0]
@@ -45,43 +65,64 @@ def pad_forest_blocks(feature, threshold, leaf, block_t: int):
     return feature, threshold, leaf, rem_t
 
 
-def _tree_kernel(x_ref, f_ref, t_ref, l_ref, o_ref, *, depth: int, n_trees: int):
+def forest_votes(x, f_ref, t_ref, l_ref, *, t0: int, n_trees: int,
+                 depth: int):
+    """Sum of leaf rows over trees ``[t0, t0 + n_trees)`` for each flow.
+
+    `x` is the ``(bn, F)`` feature tile; `f_ref`/`t_ref`/`l_ref` hold the
+    node feature ids, thresholds and leaves in `kernel_layout`. Per level,
+    a one-hot over the node axis reads each flow's feature id and
+    threshold — only the 128-lane
+    aligned window that holds the level's nodes — and a one-hot over the
+    feature axis reads the feature value. A one-hot × leaf matmul at
+    HIGHEST precision reads the votes exactly (a one-hot row times a
+    float32 table, summed with zeros). Every read is exact, so the
+    traversal takes the same branches as `ref.forest_infer_ref`.
+    """
+    bn, F = x.shape
+    NI = f_ref.shape[2]
+    K, NL = l_ref.shape[1], l_ref.shape[2]
+    f_lane = lax.broadcasted_iota(jnp.int32, (bn, F), 1)
+    l_lane = lax.broadcasted_iota(jnp.int32, (bn, NL), 1)
+
+    def tree(t, acc):
+        node = jnp.zeros((bn, 1), jnp.int32)
+        for d in range(depth):
+            # level d's nodes are [2^d - 1, 2^(d+1) - 1)
+            lo = (2 ** d - 1) // 128 * 128
+            hi = min(NI, -(-(2 ** (d + 1) - 1) // 128) * 128)
+            at = lax.broadcasted_iota(jnp.int32, (bn, hi - lo), 1) + lo == node
+            fid = jnp.sum(jnp.where(at, f_ref[t, :, lo:hi], 0),
+                          axis=1, keepdims=True)
+            thr = jnp.sum(jnp.where(at, t_ref[t, :, lo:hi], 0.0),
+                          axis=1, keepdims=True)
+            xv = jnp.sum(jnp.where(f_lane == fid, x, 0.0),
+                         axis=1, keepdims=True)
+            node = 2 * node + 1 + (xv > thr).astype(jnp.int32)
+        at_leaf = (l_lane == node - (2 ** depth - 1)).astype(jnp.float32)
+        return acc + lax.dot_general(
+            at_leaf, l_ref[t], (((1,), (1,)), ((), ())),
+            precision=lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32)
+
+    return lax.fori_loop(t0, t0 + n_trees, tree,
+                         jnp.zeros((bn, K), jnp.float32))
+
+
+def _tree_kernel(x_ref, f_ref, t_ref, l_ref, o_ref, *, depth: int,
+                 n_trees: int):
     j = pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    x = x_ref[...]                      # (bn, F)
-    feat = f_ref[...]                   # (bt, NI)
-    thr = t_ref[...]                    # (bt, NI)
-    leaf = l_ref[...]                   # (bt, NL, K)
-    bn = x.shape[0]
-    bt = feat.shape[0]
+    o_ref[...] += forest_votes(x_ref[...], f_ref, t_ref, l_ref, t0=0,
+                               n_trees=f_ref.shape[0], depth=depth)
 
-    node = jnp.zeros((bn, bt), jnp.int32)
-    for _ in range(depth):
-        # gather per (flow, tree): feature id + threshold at current node
-        f = jnp.take_along_axis(
-            jnp.broadcast_to(feat[None], (bn, bt, feat.shape[1])),
-            node[:, :, None], axis=2,
-        )[..., 0]
-        th = jnp.take_along_axis(
-            jnp.broadcast_to(thr[None], (bn, bt, thr.shape[1])),
-            node[:, :, None], axis=2,
-        )[..., 0]
-        xv = jnp.take_along_axis(
-            jnp.broadcast_to(x[:, None, :], (bn, bt, x.shape[1])),
-            f.astype(jnp.int32)[:, :, None], axis=2,
-        )[..., 0]
-        node = 2 * node + 1 + (xv > th).astype(jnp.int32)
-
-    leaf_idx = node - (2 ** depth - 1)                     # (bn, bt)
-    votes = jnp.take_along_axis(
-        jnp.broadcast_to(leaf[None], (bn,) + leaf.shape),
-        leaf_idx[:, :, None, None], axis=2,
-    )[:, :, 0, :]                                           # (bn, bt, K)
-    o_ref[...] += votes.sum(axis=1) / n_trees
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _mean():
+        o_ref[...] = o_ref[...] / n_trees
 
 
 def forest_infer_kernel_call(
@@ -103,29 +144,25 @@ def forest_infer_kernel_call(
     # pad both grid axes up to their block multiples so arbitrary batch and
     # forest sizes work (and the path has no asserts to lose under -O):
     # padded flows are zero rows whose output is sliced off; padded trees
-    # are pass-through (+inf threshold, zero leaves) and the vote mean is
-    # rescaled back to the true tree count afterwards.
+    # are pass-through (+inf threshold, zero leaves) and add nothing.
     rem_n = (-N) % bn
     if rem_n:
         x = jnp.pad(x, ((0, rem_n), (0, 0)))
     feature, threshold, leaf, rem_t = pad_forest_blocks(
         feature, threshold, leaf, bt)
 
-    kern = functools.partial(_tree_kernel, depth=depth, n_trees=T + rem_t)
+    kern = functools.partial(_tree_kernel, depth=depth, n_trees=T)
     out = pl.pallas_call(
         kern,
         grid=((N + rem_n) // bn, (T + rem_t) // bt),
         in_specs=[
             pl.BlockSpec((bn, F), lambda i, j: (i, 0)),
-            pl.BlockSpec((bt, NI), lambda i, j: (j, 0)),
-            pl.BlockSpec((bt, NI), lambda i, j: (j, 0)),
-            pl.BlockSpec((bt, NL, K), lambda i, j: (j, 0, 0)),
+            pl.BlockSpec((bt, 1, NI), lambda i, j: (j, 0, 0)),
+            pl.BlockSpec((bt, 1, NI), lambda i, j: (j, 0, 0)),
+            pl.BlockSpec((bt, K, NL), lambda i, j: (j, 0, 0)),
         ],
         out_specs=pl.BlockSpec((bn, K), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((N + rem_n, K), jnp.float32),
         interpret=interpret,
-    )(x, feature, threshold, leaf)
-    if rem_t:
-        # the kernel averaged over the padded tree count; restore true mean
-        out = out * ((T + rem_t) / T)
+    )(x, *kernel_layout(feature, threshold, leaf))
     return out[:N]

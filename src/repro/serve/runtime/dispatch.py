@@ -27,8 +27,8 @@ The flush hot path is allocation-free (DESIGN.md §7): the ready queue is an
 array-backed FIFO drained by slicing (no per-item popleft), and each shape
 bucket owns ``max_pending + 1`` preallocated **staging arenas** —
 `TrafficDataset`s whose tensors are reused round-robin across flushes
-(flags staged as float32, so the extraction engine never converts on the
-hot path). The rotation depth is the donation-safety contract: the XLA CPU
+(flags staged as the table's uint8 planes; the pipeline bit-packs them on
+device). The rotation depth is the donation-safety contract: the XLA CPU
 client may alias host buffers zero-copy at submit, so an arena is only
 reused once its batch has provably left the pending window.
 
@@ -230,7 +230,6 @@ class MicroBatchDispatcher:
         self._pending: deque[BatchRecord] = deque()
         self._arenas: dict[int, list[TrafficDataset]] = {}
         self._arena_turn: dict[int, int] = {}
-        self._flag_scratch: dict[int, np.ndarray] = {}
         self.results: dict[int, object] = {}  # flow_id -> predicted class
         # refreshed predictions for still-live frozen flows: `results` keeps
         # first-prediction-wins semantics (bit-identical to non-reuse runs),
@@ -539,8 +538,7 @@ class MicroBatchDispatcher:
 
     def _arena(self, bucket: int) -> TrafficDataset:
         """Preallocated staging batch for this shape bucket, reused across
-        flushes. Flags are staged as float32 so `extraction_fn` skips its
-        per-batch convert.
+        flushes. Flags are staged as the table's uint8 planes.
 
         ``max_pending + 1`` arenas rotate per bucket: the XLA CPU client may
         alias host numpy buffers zero-copy instead of copying at submit, so
@@ -558,7 +556,7 @@ class MicroBatchDispatcher:
                     direction=np.zeros((bucket, P), np.uint8),
                     ttl=np.zeros((bucket, P), np.float32),
                     winsize=np.zeros((bucket, P), np.float32),
-                    flags=np.zeros((bucket, P, 8), np.float32),
+                    flags=np.zeros((bucket, P, 8), np.uint8),
                     flow_len=np.zeros(bucket, np.int32),
                     proto=np.zeros(bucket, np.float32),
                     s_port=np.zeros(bucket, np.float32),
@@ -576,24 +574,16 @@ class MicroBatchDispatcher:
 
     def gather(self, slots: np.ndarray, bucket: int) -> TrafficDataset:
         """Fill this bucket's staging arena from table rows (allocation-free:
-        every destination, including the uint8 flags scratch the float32
-        cast reads through, is preallocated per bucket)."""
+        every destination is preallocated per bucket)."""
         t = self.table
         n = len(slots)
         ds = self._arena(bucket)
         for dst, src in (
             (ds.ts, t.ts), (ds.size, t.size), (ds.direction, t.direction),
-            (ds.ttl, t.ttl), (ds.winsize, t.winsize),
+            (ds.ttl, t.ttl), (ds.winsize, t.winsize), (ds.flags, t.flags),
         ):
             np.take(src, slots, axis=0, out=dst[:n])
             dst[n:] = 0
-        scratch = self._flag_scratch.get(bucket)
-        if scratch is None:
-            scratch = np.zeros((bucket, t.pkt_depth, 8), np.uint8)
-            self._flag_scratch[bucket] = scratch
-        np.take(t.flags, slots, axis=0, out=scratch[:n])
-        ds.flags[:n] = scratch[:n]     # casting copy into the staged float32
-        ds.flags[n:] = 0
         ds.flow_len[:n] = t.ctrl["count"][slots]
         ds.flow_len[n:] = 0
         for dst, src in (

@@ -14,12 +14,14 @@ tuple of per-feature op descriptors that is hashable and order-preserving.
 The plan is the unit of specialization shared by both execution paths —
 `_extract` (the standalone XLA extraction stage) and the fused Pallas
 pipeline kernel (`repro.kernels.fused_pipeline`) trace the *same* emitter
-(`emit_feature_columns`) over it, which is what makes the fused path
-bit-identical to the unfused one (DESIGN.md §7).
+(`emit_feature_columns`) over it, so both compute the same formulas; the
+fused path matches the unfused one to float32 rounding (DESIGN.md §7).
 
 All statistics are masked segmented reductions over dense
-``(flows, max_pkts)`` tensors — the layout the Pallas `feature_extract`
-kernel mirrors for the TPU hot path.
+``(flows, max_pkts)`` tensors, written only with ops that Mosaic lowers
+inside a Pallas kernel: no sort, cummax or gather (the median counts
+ranks, the iat running max doubles lane shifts, handshake timestamps are
+masked minima).
 """
 from __future__ import annotations
 
@@ -35,6 +37,7 @@ from .synth import FLAG_NAMES, TrafficDataset
 __all__ = [
     "extract_features",
     "extraction_fn",
+    "pack_flags",
     "stats_plan",
     "emit_feature_columns",
     "emit_agg_features",
@@ -82,14 +85,49 @@ def _masked_std(v, m):
 
 
 def _masked_median(v, m):
+    """Mean of the ``(c-1)//2``-th and ``c//2``-th smallest masked values.
+
+    No sort (Mosaic lowers none): the r-th order statistic is the least
+    value u with ``#{v <= u} > r``, counted pairwise over the P columns
+    (a loop, so compile time does not grow with P). That is exactly the
+    sorted array's entry r, ties included."""
     filled = jnp.where(m, v, _BIG)
-    srt = jnp.sort(filled, axis=1)
-    c = jnp.sum(m, axis=1)
-    lo_i = jnp.maximum((c - 1) // 2, 0)
-    hi_i = jnp.maximum(c // 2, 0)
-    lo = jnp.take_along_axis(srt, lo_i[:, None], axis=1)[:, 0]
-    hi = jnp.take_along_axis(srt, hi_i[:, None], axis=1)[:, 0]
-    return jnp.where(c > 0, 0.5 * (lo + hi), 0.0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, filled.shape, 1)
+    c = jnp.sum(m, axis=1, keepdims=True)
+    lo_r = jnp.maximum((c - 1) // 2, 0)
+    hi_r = c // 2
+
+    def rank(i, lo_hi):
+        lo, hi = lo_hi
+        u = jnp.max(jnp.where(lane == i, filled, -_BIG), axis=1, keepdims=True)
+        n_le = jnp.sum((filled <= u).astype(jnp.int32), axis=1, keepdims=True)
+        return (jnp.minimum(lo, jnp.where(n_le > lo_r, u, _BIG)),
+                jnp.minimum(hi, jnp.where(n_le > hi_r, u, _BIG)))
+
+    big = jnp.full(c.shape, _BIG, filled.dtype)
+    lo, hi = jax.lax.fori_loop(0, v.shape[1], rank, (big, big))
+    return jnp.where(c > 0, 0.5 * (lo + hi), 0.0)[:, 0]
+
+
+def _exclusive_running_max(a):
+    """``out[:, j] = max(a[:, :j])`` (``-_BIG`` for j = 0): a running max
+    over the static P columns by log-step doubling of lane shifts, which
+    Mosaic lowers (it has no cummax). Max is exact, so this equals an
+    exclusive ``lax.cummax``."""
+    P = a.shape[1]
+
+    def shift(x, s):
+        if s >= P:
+            return jnp.full_like(x, -_BIG)
+        return jnp.concatenate(
+            [jnp.full((x.shape[0], s), -_BIG, x.dtype), x[:, :P - s]], axis=1)
+
+    run = shift(a, 1)
+    s = 1
+    while s < P:
+        run = jnp.maximum(run, shift(run, s))
+        s *= 2
+    return run
 
 
 _STATS = {
@@ -102,6 +140,14 @@ _STATS = {
 }
 
 _FLAG_IDX = {n: i for i, n in enumerate(FLAG_NAMES)}
+
+
+def pack_flags(flags):
+    """``(rows, P, 8)`` 0/1 flag planes -> ``(rows, P)`` int32 bit mask,
+    bit k set when flag ``FLAG_NAMES[k]`` is. The emitters read flags in
+    this form: one lane-dense tile instead of an 8-lane last axis."""
+    bits = jnp.asarray(flags).astype(jnp.int32)
+    return jnp.sum(bits << jnp.arange(8, dtype=jnp.int32), axis=2)
 
 
 # ---------------------------------------------------------------------------
@@ -151,10 +197,10 @@ def emit_feature_columns(
 
     The single source of op emission for both execution paths: `_extract`
     calls it on full-batch tensors, the fused pipeline kernel on per-block
-    VMEM tiles. Returns a list of float32 (rows,) columns in plan order.
+    VMEM tiles. `flags` is the `pack_flags` bit mask. Returns a list of
+    float32 (rows,) columns in plan order.
     """
-    P = ts.shape[1]
-    idx = jnp.arange(P)[None, :]
+    idx = jax.lax.broadcasted_iota(jnp.int32, ts.shape, 1)
     valid = (idx < flow_len[:, None]) & (idx < depth)
 
     dir_mask = {
@@ -162,47 +208,54 @@ def emit_feature_columns(
         "d": valid & (direction == 1),
     }
 
+    # shared sub-expressions are traced once per call: a Pallas kernel body
+    # gets no common-subexpression pass before Mosaic compiles it
+    @functools.cache
+    def flag(k):
+        return (flags >> k) & 1
+
+    @functools.cache
+    def dur():
+        return _masked_max(ts, valid) - _masked_min(ts, valid)
+
     # directional inter-arrival times: ts_i - ts(previous pkt, same dir).
     # ts is monotone within a flow, so the previous same-direction timestamp
-    # is an exclusive cumulative max over masked timestamps.
-    def dir_iat(m):
-        masked_ts = jnp.where(m, ts, -_BIG)
-        cm = jax.lax.cummax(masked_ts, axis=1)
-        prev = jnp.concatenate(
-            [jnp.full((ts.shape[0], 1), -_BIG, ts.dtype), cm[:, :-1]], axis=1
-        )
+    # is an exclusive running max over masked timestamps.
+    @functools.cache
+    def dir_iat(d):
+        m = dir_mask[d]
+        prev = _exclusive_running_max(jnp.where(m, ts, -_BIG))
         has_prev = prev > -_BIG / 2
         iat = jnp.where(m & has_prev, ts - prev, 0.0)
         return iat, m & has_prev
 
+    # first matching packet's ts: ts is monotone within a flow, so the
+    # first match is the masked minimum (0.0 when nothing matches)
+    @functools.cache
+    def handshake_ts():
+        syn = flag(_FLAG_IDX["syn"]) > 0
+        ack = flag(_FLAG_IDX["ack"]) > 0
+        return (_masked_min(ts, valid & syn & ~ack),
+                _masked_min(ts, valid & syn & ack),
+                _masked_min(ts, valid & ack & ~syn))
+
     fields = {"bytes": size, "winsize": winsize, "ttl": ttl}
     meta = {"proto": proto, "s_port": s_port, "d_port": d_port}
-
-    def first_ts(cond):
-        any_ = jnp.any(cond, axis=1)
-        i = jnp.argmax(cond, axis=1)
-        return jnp.where(any_, jnp.take_along_axis(ts, i[:, None], axis=1)[:, 0], 0.0)
 
     cols = []
     for entry in plan:
         kind = entry[0]
         if kind == "dur":
-            c = _masked_max(ts, valid) - _masked_min(ts, valid)
+            c = dur()
         elif kind == "meta":
             c = meta[entry[1]]
         elif kind == "load":
-            d = entry[1]
-            dur = _masked_max(ts, valid) - _masked_min(ts, valid)
-            byt = _masked_sum(size, dir_mask[d])
-            c = jnp.where(dur > 0, byt * 8.0 / jnp.maximum(dur, 1e-9), 0.0)
+            byt = _masked_sum(size, dir_mask[entry[1]])
+            c = jnp.where(dur() > 0, byt * 8.0 / jnp.maximum(dur(), 1e-9), 0.0)
         elif kind == "pkt_cnt":
             c = jnp.sum(dir_mask[entry[1]], axis=1).astype(jnp.float32)
         elif kind == "handshake":
-            syn = flags[:, :, _FLAG_IDX["syn"]] > 0
-            ack = flags[:, :, _FLAG_IDX["ack"]] > 0
-            t_syn = first_ts(valid & syn & ~ack)
-            t_synack = first_ts(valid & syn & ack)
-            t_ack = first_ts(valid & ack & ~syn)
+            t_syn, t_synack, t_ack = handshake_ts()
             if entry[1] == "tcp_rtt":
                 c = jnp.maximum(t_ack - t_syn, 0.0)
             elif entry[1] == "syn_ack":
@@ -211,12 +264,12 @@ def emit_feature_columns(
                 c = jnp.maximum(t_ack - t_synack, 0.0)
         elif kind == "flag_cnt":
             c = jnp.sum(
-                jnp.where(valid, flags[:, :, entry[1]], 0), axis=1
+                jnp.where(valid, flag(entry[1]), 0), axis=1
             ).astype(jnp.float32)
         else:  # ("stat", dir, family, stat)
             _, d, fam, stat = entry
             if fam == "iat":
-                v, m = dir_iat(dir_mask[d])
+                v, m = dir_iat(d)
             else:
                 v, m = fields[fam], dir_mask[d]
             c = _STATS[stat](v, m)
@@ -291,7 +344,7 @@ def emit_merged_columns(
             plan,
             ts=ts[:, :dd], size=size[:, :dd], direction=direction[:, :dd],
             ttl=ttl[:, :dd], winsize=winsize[:, :dd],
-            flags=flags[:, :dd, :], flow_len=flow_len,
+            flags=flags[:, :dd], flow_len=flow_len,
             proto=proto, s_port=s_port, d_port=d_port, depth=dd,
         )
         for i, c in zip(idxs, cols):
@@ -486,7 +539,7 @@ def _extract(
     cols = emit_feature_columns(
         stats_plan(names),
         ts=ts, size=size, direction=direction, ttl=ttl, winsize=winsize,
-        flags=flags, flow_len=flow_len, proto=proto, s_port=s_port,
+        flags=pack_flags(flags), flow_len=flow_len, proto=proto, s_port=s_port,
         d_port=d_port, depth=depth,
     )
     return jnp.stack(cols, axis=1)
@@ -501,13 +554,9 @@ def extraction_fn(names: Sequence[str], depth: int, max_pkts: int):
     names = tuple(names)
 
     def run(ds: TrafficDataset):
-        # the streaming dispatcher's staging arenas store flags as float32
-        # already (DESIGN.md §7); only batch-path uint8 flags pay the convert
-        flags = ds.flags if ds.flags.dtype == np.float32 \
-            else ds.flags.astype(np.float32)
         return _extract(
             ds.ts, ds.size, ds.direction, ds.ttl, ds.winsize,
-            flags, ds.flow_len, ds.proto, ds.s_port,
+            ds.flags, ds.flow_len, ds.proto, ds.s_port,
             ds.d_port, names=names, depth=int(depth), max_pkts=max_pkts,
         )
 

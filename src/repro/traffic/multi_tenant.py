@@ -14,8 +14,9 @@ state. Here the sharing is structural:
   Pallas kernel (`fused_multi_forest_infer` — tenant-stacked forests over
   the shared in-VMEM feature tile); unfused mode gathers each tenant's
   columns from the merged matrix and runs the solo forest kernel per
-  tenant. Both are bit-identical, tenant by tenant, to running each
-  pipeline alone.
+  tenant. Tenant by tenant, the unfused path is bit-identical to running
+  each pipeline alone, and the fused launch matches it to float32
+  rounding with equal predictions.
 - **Co-optimization**: `MultiTenantRep`/`MultiTenantSpace`/
   `MultiTenantProfiler` expose the joint configuration space to
   `CatoOptimizer` with the union-plan cost (shared ops counted once) —
@@ -47,6 +48,7 @@ from .extraction import (
     emit_merged_columns,
     merge_stats_plans,
     merged_plan_is_incremental,
+    pack_flags,
     stats_plan,
 )
 from .features import modeled_extraction_cost_ns
@@ -85,7 +87,7 @@ def _merged_extract(
     cols = emit_merged_columns(
         merged,
         ts=ts, size=size, direction=direction, ttl=ttl, winsize=winsize,
-        flags=flags, flow_len=flow_len, proto=proto, s_port=s_port,
+        flags=pack_flags(flags), flow_len=flow_len, proto=proto, s_port=s_port,
         d_port=d_port,
     )
     return jnp.stack(cols, axis=1)
@@ -174,7 +176,7 @@ class MultiTenantPipeline:
                 direction=np.zeros((b, P), np.uint8),
                 ttl=np.zeros((b, P), np.float32),
                 winsize=np.zeros((b, P), np.float32),
-                flags=np.zeros((b, P, 8), np.float32),
+                flags=np.zeros((b, P, 8), np.uint8),
                 flow_len=np.zeros(b, np.int32),
                 proto=np.zeros(b, np.float32),
                 s_port=np.zeros(b, np.float32),
@@ -255,10 +257,8 @@ def build_multi_tenant_pipeline(
                 )
     else:
         def run(ds: TrafficDataset):
-            flags = ds.flags if ds.flags.dtype == np.float32 \
-                else ds.flags.astype(np.float32)
             X = _merged_extract(
-                ds.ts, ds.size, ds.direction, ds.ttl, ds.winsize, flags,
+                ds.ts, ds.size, ds.direction, ds.ttl, ds.winsize, ds.flags,
                 ds.flow_len, ds.proto, ds.s_port, ds.d_port, merged=merged)
             return infer_tenants(X)
 

@@ -16,9 +16,11 @@ stage. Two fusion levels exist:
 - ``fused=True`` (one launch): the `fused_pipeline` Pallas kernel computes
   the feature columns from the static stats plan *inside* the flow tile and
   runs the forest traversal on the in-register features — no HBM
-  materialization, donated input buffers (DESIGN.md §7). Bit-identical to
-  the unfused path: both trace the same column emitter and the same
-  traversal/vote order.
+  materialization, donated input buffers (DESIGN.md §7). Both paths trace
+  the same column emitter and the same traversal (`forest_votes`), so they
+  agree with the float32 reference (``use_kernel=False``) on every
+  predicted class and to 1e-5 in probability; they are not bitwise equal,
+  since a kernel body may round a reduction differently from XLA.
 
 This is the deployable artifact — `examples/deploy_pipeline.py` drives it.
 """
@@ -130,7 +132,7 @@ class ServingPipeline:
                 direction=np.zeros((b, P), np.uint8),
                 ttl=np.zeros((b, P), np.float32),
                 winsize=np.zeros((b, P), np.float32),
-                flags=np.zeros((b, P, 8), np.float32),
+                flags=np.zeros((b, P, 8), np.uint8),
                 flow_len=np.zeros(b, np.int32),
                 proto=np.zeros(b, np.float32),
                 s_port=np.zeros(b, np.float32),

@@ -139,3 +139,21 @@ def test_chunked_ssd_matches_kernel_path():
     y_model, _ = chunked_ssd(x, dt * A, dt, Bm[:, :, None], Cm[:, :, None], chunk=32)
     y_ref = ref.mamba_scan_ref(x, dt, A, Bm, Cm)
     np.testing.assert_allclose(np.asarray(y_model), np.asarray(y_ref), atol=3e-4)
+
+
+@pytest.mark.parametrize("backend,interpret,want", [
+    ("cpu", None, True), ("cpu", True, True), ("cpu", False, False),
+    ("tpu", None, False), ("tpu", False, False), ("gpu", False, False),
+    ("tpu", True, RuntimeError), ("gpu", True, RuntimeError),
+    ("gpu", None, RuntimeError),
+])
+def test_resolve_interpret_never_interprets_off_cpu(
+        monkeypatch, backend, interpret, want):
+    """Interpret mode runs only on the CPU backend: a served kernel on an
+    accelerator compiles with Mosaic or raises, never interprets."""
+    monkeypatch.setattr(ops.jax, "default_backend", lambda: backend)
+    if want is RuntimeError:
+        with pytest.raises(RuntimeError):
+            ops.resolve_interpret(interpret)
+    else:
+        assert ops.resolve_interpret(interpret) is want

@@ -156,7 +156,7 @@ def test_union_columns_match_solo_extraction_property(ds):
     """Random tenant sets: merged matrix column subsets == solo extracts."""
     import jax.numpy as jnp
 
-    from repro.traffic.extraction import emit_merged_columns
+    from repro.traffic.extraction import emit_merged_columns, pack_flags
 
     rng = np.random.default_rng(7)
     for _ in range(5):
@@ -172,7 +172,7 @@ def test_union_columns_match_solo_extraction_property(ds):
             merged, ts=jnp.asarray(u.ts), size=jnp.asarray(u.size),
             direction=jnp.asarray(u.direction), ttl=jnp.asarray(u.ttl),
             winsize=jnp.asarray(u.winsize),
-            flags=jnp.asarray(u.flags, jnp.float32),
+            flags=pack_flags(u.flags),
             flow_len=jnp.asarray(u.flow_len), proto=jnp.asarray(u.proto),
             s_port=jnp.asarray(u.s_port), d_port=jnp.asarray(u.d_port))
         X = np.stack([np.asarray(c) for c in out], axis=1)
