@@ -153,6 +153,9 @@ def fused_pipeline_call(
         out_specs=pl.BlockSpec((bn, K), _tile),
         out_shape=jax.ShapeDtypeStruct((N + rem_n, K), jnp.float32),
         interpret=interpret,
+        # the kernel's instruction name in a device trace
+        # (%fused_forest_infer.N); unnamed, it would follow the jit wrapper
+        name="fused_forest_infer",
     )(ts, size, direction, ttl, winsize, flags, meta, feature, threshold, leaf)
     return out[:N]
 

@@ -6,8 +6,10 @@ One subsystem spanning the serving stack, four pieces:
   histogram, and telemetry view behind one dotted namespace with exact
   snapshot/delta semantics and order-independent cross-shard merge.
 - `trace` — the bounded ring-buffer `Tracer`: per-flow lifecycle spans
-  and per-worker stage spans on the replay packet clock, sampled,
-  off by default, exported as Chrome trace-event JSON.
+  and per-worker stage spans on the caller's clock, sampled, off by
+  default, exported as Chrome trace-event JSON; plus the served path's
+  wall-clock layer spans and counters (`Tracer.layers`), annotated for
+  the profiler as ``cato.<layer>``.
 - `audit` — the control-plane `AuditLog`: every rebalance / retire /
   scale / hot-swap decision as a structured event with before/after
   EWMA snapshots and the planner's rationale.
@@ -24,8 +26,8 @@ One subsystem spanning the serving stack, four pieces:
 
 `Observability` bundles the live hooks and knows how to attach them to
 a runtime (single or sharded): attachment is attribute injection on the
-dispatchers and metrics blocks, so a runtime with no bundle attached
-pays exactly one ``is not None`` test per hook site.
+dispatchers, flow tables and metrics blocks, so a runtime with no bundle
+attached pays exactly one ``is not None`` test per hook site.
 """
 from __future__ import annotations
 
@@ -124,6 +126,7 @@ class Observability:
         workers through here so their spans carry the right shard pid)."""
         disp = worker.dispatcher
         disp.tracer = self.tracer
+        worker.table.tracer = self.tracer
         disp.drift = self.drift
         disp.trace_pid = shard_id
         if self.latency is not None and worker.metrics.latency_components is None:
@@ -132,7 +135,7 @@ class Observability:
     def snapshot(self, runtime, control=None) -> dict:
         """One frozen document for the whole run: the merged fleet
         registry snapshot plus whatever else is live (control summary,
-        drift signal, audit and trace summaries)."""
+        drift signal, audit and trace summaries, the layer table)."""
         out = {"registry": fleet_registry(runtime).snapshot()}
         if control is not None:
             out["control"] = control.summary()
@@ -145,4 +148,5 @@ class Observability:
             out["audit"] = self.audit.summary()
         if self.tracer is not None:
             out["trace"] = self.tracer.summary()
+            out["layers"] = self.tracer.layers()
         return out

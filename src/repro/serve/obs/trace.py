@@ -1,10 +1,10 @@
-"""Bounded ring-buffer span tracer on the replay packet clock
+"""Bounded ring-buffer span tracer, plus wall-clock layer spans
 (DESIGN.md §11.2).
 
-Spans are recorded against *virtual* time — the same two-lane
-`_WorkerClock` seconds every latency number already uses — so a trace of
-a replay is exactly as deterministic as the replay itself. Two span
-families:
+The ring holds events on the **caller's clock** — the `now` a runtime is
+handed: wall time in live use, the replay's virtual two-lane
+`_WorkerClock` seconds under replay — so a trace of a replay is exactly
+as deterministic as the replay itself. Two span families live there:
 
 - **worker stage spans** (Chrome ``ph: "X"`` complete events): per-block
   ingest service envelopes and per-batch inference service, charged by
@@ -12,7 +12,9 @@ families:
   ``tid`` the lane (0 = ingest, 1 = inference, 2 = control).
 - **flow lifecycle spans** (Chrome async ``b``/``n``/``e`` events keyed
   by flow id): ingest (first packet) → ready → flush (with reason) →
-  emit (prediction resolved at the inference-lane completion edge).
+  emit. The emit edge is the batch's resolve (`BatchRecord.resolved_ts`)
+  in live use, and the inference lane's completion edge under replay
+  (`replay_clock`).
 
 Storage is a preallocated numpy ring of `capacity` events — recording
 never allocates per event on the vectorized path and never grows; once
@@ -21,22 +23,36 @@ Flows are sampled at `sample` by a deterministic hash threshold on the
 flow id, so a 1% trace keeps *whole* lifecycles, never partial ones, and
 two replays of the same stream sample the same flows.
 
+**Layer spans** are the served path's own timing, on the wall clock
+(``time.perf_counter_ns``), and never enter the ring. A hook site opens
+one with `layer(name, items)` while the tracer is enabled. Each span adds
+calls, items, total ns and self ns (total less what its child spans
+cover) to a per-name table and, while a profiler capture runs, enters
+``jax.profiler.TraceAnnotation("cato.<name>")``, so the capture shows it
+on the host plane, on the same clock as the device ops. `count(name, k)` adds to a counter;
+XLA compiles are counted (``jax.compiles``) while the tracer is enabled.
+`layers()` returns the table and the counters.
+
 `chrome()` exports the Chrome trace-event JSON (``chrome://tracing`` /
 Perfetto load it directly); timestamps are exported in microseconds.
 
 Tracing is **off by default** everywhere: every hook site guards on
-``tracer is not None`` and the tracer itself no-ops when
-``enabled=False``, so the untraced hot path pays one attribute test.
+``tracer is not None`` (and ``tracer.enabled`` before opening a layer
+span), so the untraced hot path pays one attribute test per site.
 """
 from __future__ import annotations
 
 import json
 import pathlib
+import weakref
+from time import perf_counter_ns
 from typing import Optional
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
-__all__ = ["Tracer", "TID_INGEST", "TID_INFER", "TID_CONTROL", "TID_TENANT0"]
+__all__ = ["COMPILES", "Tracer", "TID_INGEST", "TID_INFER", "TID_CONTROL",
+           "TID_TENANT0"]
 
 TID_INGEST = 0
 TID_INFER = 1
@@ -51,6 +67,85 @@ _TID_NAMES = {TID_INGEST: "ingest lane", TID_INFER: "inference lane",
 # event phases, packed as u1
 _PH_X, _PH_B, _PH_E, _PH_N, _PH_I = 0, 1, 2, 3, 4
 _PH_CHR = {_PH_X: "X", _PH_B: "b", _PH_E: "e", _PH_N: "n", _PH_I: "i"}
+
+
+# layer spans: profiler label prefix, and the counter of XLA compiles seen
+# while a tracer is enabled (JAX's `jax._src.dispatch.BACKEND_COMPILE_EVENT`)
+LAYER_PREFIX = "cato."
+COMPILES = "jax.compiles"
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_LIVE: "weakref.WeakSet[Tracer]" = weakref.WeakSet()
+_listening = False
+
+
+def _on_duration(event: str, duration: float, **_) -> None:
+    if event == _COMPILE_EVENT:
+        for tr in list(_LIVE):
+            if tr.enabled:
+                tr.count(COMPILES)
+
+
+def _listen(tracer: "Tracer") -> None:
+    """Count compiles for `tracer`; JAX's listener is registered once per
+    process."""
+    global _listening
+    _LIVE.add(tracer)
+    if not _listening:
+        import jax
+
+        jax.monitoring.register_event_duration_secs_listener(_on_duration)
+        _listening = True
+
+
+class _Layer:
+    """One layer span of a `Tracer` (`Tracer.layer`): a context manager,
+    or `start()` / `stop(items)` where a ``with`` block does not fit."""
+
+    __slots__ = ("_tracer", "name", "items", "_ann", "_parent", "_child",
+                 "_t0")
+
+    def __init__(self, tracer: "Tracer", name: str, items: int):
+        self._tracer = tracer
+        self.name = name
+        self.items = items
+
+    def start(self) -> "_Layer":
+        tr = self._tracer
+        self._parent = tr._open
+        tr._open = self
+        self._child = 0
+        self._t0 = perf_counter_ns()
+        # the annotation costs as much as the rest of the span: enter it
+        # only while a profiler capture is running
+        if TraceAnnotation.is_enabled():
+            self._ann = TraceAnnotation(LAYER_PREFIX + self.name)
+            self._ann.__enter__()
+        else:
+            self._ann = None
+        return self
+
+    def stop(self, items: Optional[int] = None) -> None:
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+        dur = perf_counter_ns() - self._t0
+        tr = self._tracer
+        parent = tr._open = self._parent
+        if parent is not None:
+            parent._child += dur
+        if items is not None:
+            self.items = items
+        row = tr._rows.get(self.name)
+        if row is None:
+            row = tr._rows[self.name] = [0, 0, 0, 0]
+        row[0] += 1
+        row[1] += self.items
+        row[2] += dur
+        row[3] += dur - self._child
+
+    __enter__ = start
+
+    def __exit__(self, *exc) -> None:
+        self.stop()
 
 
 def _mix64(x: np.ndarray) -> np.ndarray:
@@ -85,7 +180,7 @@ class Tracer:
         cap = self.capacity
         self._ph = np.zeros(cap, np.uint8)
         self._name = np.zeros(cap, np.int32)
-        self._ts = np.zeros(cap, np.float64)    # virtual seconds
+        self._ts = np.zeros(cap, np.float64)    # seconds on the caller's clock
         self._dur = np.zeros(cap, np.float64)
         self._pid = np.zeros(cap, np.int32)
         self._tid = np.zeros(cap, np.int32)
@@ -93,6 +188,15 @@ class Tracer:
         self._names: list[str] = []
         self._intern: dict[str, int] = {}
         self.total = 0                           # events ever recorded
+        # set by the replay's worker clock: lifecycles close on it, at the
+        # inference lane's completion edge, not at the dispatcher's resolve
+        self.replay_clock = False
+        # layer spans: name -> [calls, items, total ns, self ns]; counters;
+        # the innermost open span
+        self._rows: dict[str, list] = {}
+        self._counters: dict[str, int] = {}
+        self._open: Optional[_Layer] = None
+        _listen(self)
 
     # -- bookkeeping ---------------------------------------------------------
 
@@ -194,6 +298,25 @@ class Tracer:
         """Close lifecycles at the prediction-emit edge."""
         self._flow_event(_PH_E, "flow", ids, ts, pid)
 
+    # -- layer spans and counters (wall clock; never in the ring) ------------
+
+    def layer(self, name: str, items: int = 0) -> _Layer:
+        """A layer span over `items` (packets, flows, bytes...). Hook sites
+        open one only while the tracer is enabled."""
+        return _Layer(self, name, items)
+
+    def count(self, name: str, k: int = 1) -> None:
+        self._counters[name] = self._counters.get(name, 0) + k
+
+    def layers(self) -> dict:
+        """The layer table and the counters, as plain numbers."""
+        return {
+            "spans": {name: {"calls": r[0], "items": r[1], "total_ns": r[2],
+                             "self_ns": r[3]}
+                      for name, r in self._rows.items()},
+            "counters": dict(self._counters),
+        }
+
     # -- export --------------------------------------------------------------
 
     def events(self) -> list[dict]:
@@ -249,7 +372,7 @@ class Tracer:
             "traceEvents": meta + self.events(),
             "displayTimeUnit": "ms",
             "otherData": {
-                "clock": "virtual (replay packet clock)",
+                "clock": "the caller's now",
                 "sample_rate": self.sample,
                 "events_recorded": self.total,
                 "events_dropped": self.dropped,

@@ -93,6 +93,13 @@ def next_bucket(n: int, min_bucket: int, max_batch: int) -> int:
     return min(b, max_batch)
 
 
+def _h2d_bytes(ds: TrafficDataset) -> int:
+    """Bytes of a staged arena that a submit copies to the device."""
+    return sum(a.nbytes for a in (
+        ds.ts, ds.size, ds.direction, ds.ttl, ds.winsize, ds.flags,
+        ds.flow_len, ds.proto, ds.s_port, ds.d_port))
+
+
 def _timeout_boundary(t: np.ndarray, lo: int, hi: int, ref: float,
                       timeout: float) -> int:
     """First index k in [lo, hi) where the scalar flush predicate
@@ -192,9 +199,11 @@ class BatchRecord:
     n_anchor: int = 0          # reuse: anchors snapped/re-snapped by this batch
     probs: Optional[object] = None   # in-flight device array
     preds: Optional[np.ndarray] = None
-    # flow ids sampled into the trace (the replay clock closes their
-    # lifecycle spans at this batch's service-completion edge); None when
-    # tracing is off or no flow in the batch was sampled
+    # the `now` of the call in which the batch resolved (None until then)
+    resolved_ts: Optional[float] = None
+    # flow ids sampled into the trace (their lifecycle spans close when the
+    # batch resolves, or at its service-completion edge under replay);
+    # None when tracing is off or no flow in the batch was sampled
     trace_ids: Optional[np.ndarray] = None
 
 
@@ -236,6 +245,9 @@ class MicroBatchDispatcher:
         # so drift-triggered re-inferences land here instead
         self.live_predictions: dict[int, object] = {}
         self.records: list[BatchRecord] = []
+        # the latest `now` a flush or drain was handed: `resolve_pending`
+        # stamps the batches it resolves with it
+        self._last_now = float("nan")
         # observability hooks (repro.serve.obs): attribute injection, off
         # by default — the untraced hot path pays one `is not None` test
         self.tracer = None          # obs.Tracer
@@ -266,12 +278,19 @@ class MicroBatchDispatcher:
         each record carries `flush_idx`, the in-block index of the packet
         whose arrival triggered it (the replay clock charges the submit
         there)."""
+        ready = np.flatnonzero((statuses == int(FlowStatus.READY)) | (
+            statuses == int(FlowStatus.READY_EOF)))
+        tr = self.tracer
+        if tr is not None and tr.enabled:
+            with tr.layer("ready", ready.size):
+                return self._ingest_ready(ready, slots, t)
+        return self._ingest_ready(ready, slots, t)
+
+    def _ingest_ready(self, ready: np.ndarray, slots: np.ndarray,
+                      t: np.ndarray) -> list[BatchRecord]:
         recs: list[BatchRecord] = []
-        ready = (statuses == int(FlowStatus.READY)) | (
-            statuses == int(FlowStatus.READY_EOF))
         lo = 0
-        for j in np.flatnonzero(ready):
-            j = int(j)
+        for j in ready.tolist():
             self._timeout_scan(t, lo, j, recs)
             self._queue.push(int(slots[j]), float(t[j]))
             tj = float(t[j])
@@ -296,10 +315,11 @@ class MicroBatchDispatcher:
 
     def drain(self, now: float) -> list[BatchRecord]:
         out = []
+        self._last_now = now
         while len(self._queue):
             out.append(self._flush(now, "drain"))
         while self._pending:
-            self._resolve(self._pending.popleft())
+            self._resolve(self._pending.popleft(), now)
         return out
 
     def flush_queue(self, now: float, reason: str) -> list[BatchRecord]:
@@ -321,13 +341,23 @@ class MicroBatchDispatcher:
         """Block until every in-flight batch has resolved (hot-swap: the
         old pipeline must finish its submitted work before it is dropped,
         or its staging arenas could be retired while XLA still reads
-        them)."""
+        them). Each is stamped with the latest `now` the dispatcher saw."""
         while self._pending:
-            self._resolve(self._pending.popleft())
+            self._resolve(self._pending.popleft(), self._last_now)
 
     # -- flush mechanics -----------------------------------------------------
 
     def _flush(self, now: float, reason: str, flush_idx: int = -1) -> BatchRecord:
+        tr = self.tracer
+        if tr is not None and tr.enabled:
+            with tr.layer("flush", min(len(self._queue), self.max_batch)):
+                return self._flush_batch(now, reason, flush_idx, tr)
+        return self._flush_batch(now, reason, flush_idx, None)
+
+    def _flush_batch(self, now: float, reason: str, flush_idx: int,
+                     tr) -> BatchRecord:
+        """One flush; `tr` is the tracer when it is enabled, else None."""
+        self._last_now = now
         n = min(len(self._queue), self.max_batch)
         slots, ready = self._queue.pop_many(n)
         bucket = next_bucket(n, self.min_bucket, self.max_batch)
@@ -364,12 +394,12 @@ class MicroBatchDispatcher:
             reason=reason,
             flush_idx=flush_idx,
         )
-        tr = self.tracer
-        if tr is not None and tr.enabled:
+        if tr is not None:
             # sampled flow lifecycles: begin at first packet, milestones at
             # ready and flush (vectorized per batch; slots still hold their
-            # ctrl rows — mark_predicted below may recycle them). The
-            # replay clock closes these spans at the batch's service edge.
+            # ctrl rows — mark_predicted below may recycle them). They close
+            # when the batch resolves (`_resolve`), or under replay at the
+            # batch's service edge.
             keep = tr.sample_mask(rec.flow_ids)
             if keep.any():
                 ids = rec.flow_ids[keep]
@@ -381,7 +411,11 @@ class MicroBatchDispatcher:
                              np.full(len(ids), now), pid=pid)
                 rec.trace_ids = ids
         if self.execute:
-            ds = self.gather(slots, bucket)
+            if tr is not None:
+                with tr.layer("gather", n):
+                    ds = self.gather(slots, bucket)
+            else:
+                ds = self.gather(slots, bucket)
             if self.drift is not None:
                 # covariate-shift sketch: three cheap per-flow summaries
                 # reduced batch-at-once from the staged arena (obs.drift)
@@ -395,8 +429,12 @@ class MicroBatchDispatcher:
             # retire the oldest in-flight batch before submitting a new one:
             # at most `max_pending` batches overlap ingest at any time
             while len(self._pending) >= self.max_pending:
-                self._resolve(self._pending.popleft())
-            rec.probs = self.pipeline.predict_async(ds)
+                self._resolve(self._pending.popleft(), now)
+            if tr is not None:
+                with tr.layer("submit", _h2d_bytes(ds)):
+                    rec.probs = self.pipeline.predict_async(ds)
+            else:
+                rec.probs = self.pipeline.predict_async(ds)
             self._pending.append(rec)
         if self.reuse is not None and n:
             # snap the drift anchor at classification time, before
@@ -528,6 +566,7 @@ class MicroBatchDispatcher:
                 probs = self.pipeline.predict_agg(agg, proto, sp, dp)
                 preds = self.pipeline.finalize(probs)[:n_re]
                 rec.preds = preds
+                rec.resolved_ts = now
                 for fid, p in zip(rec.flow_ids, preds):
                     self.live_predictions[int(fid)] = p
             # re-anchor at the refreshed state so the next drift comparison
@@ -593,7 +632,23 @@ class MicroBatchDispatcher:
             dst[n:] = 0
         return ds
 
-    def _resolve(self, rec: BatchRecord) -> None:
+    def _resolve(self, rec: BatchRecord, now: float) -> None:
+        """Block on a submitted batch and emit its predictions; `now` is
+        the caller's clock at the call that resolves it."""
+        tr = self.tracer
+        if tr is not None and tr.enabled:
+            with tr.layer("resolve", rec.n_real):
+                wait = getattr(rec.probs, "block_until_ready", None)
+                if wait is not None:
+                    # the device's share of the resolve, apart from the
+                    # argmax and the copy to the host in `finalize`
+                    with tr.layer("resolve.wait"):
+                        wait()
+                self._emit(rec, now)
+        else:
+            self._emit(rec, now)
+
+    def _emit(self, rec: BatchRecord, now: float) -> None:
         dm = self.drift
         conf = None
         if dm is not None:
@@ -622,6 +677,12 @@ class MicroBatchDispatcher:
                 self.metrics.duplicate_predictions += 1
             else:
                 self.results[int(fid)] = p
+        rec.resolved_ts = now
+        if rec.trace_ids is not None:
+            tr = self.tracer
+            if tr is not None and not tr.replay_clock:
+                tr.flow_end(rec.trace_ids, np.full(len(rec.trace_ids), now),
+                            pid=self.trace_pid)
 
 
 class StreamingRuntime:
@@ -720,7 +781,15 @@ class StreamingRuntime:
         hi = min(B, lo + (disp.max_batch - len(disp._queue)))
         ref = disp._queue.head_ready() if len(disp._queue) else float(now[lo])
         k = _timeout_boundary(now, lo, B, ref, disp.flush_timeout_s)
-        return max(lo + 1, min(hi, k + 1))
+        end = max(lo + 1, min(hi, k + 1))
+        if end < B:
+            tr = disp.tracer
+            if tr is not None and tr.enabled:
+                # which bound cut the block here: a timeout boundary, or the
+                # ready queue's room before a full flush
+                tr.count("subblock.cut_timeout" if k + 1 <= hi
+                         else "subblock.cut_room")
+        return end
 
     def ingest_packets(
         self, key, now, rel_ts, size, direction, ttl, winsize, flags_byte,
@@ -739,6 +808,20 @@ class StreamingRuntime:
         `FlowStatus` values, the per-packet payload/tracker cost class, and
         the micro-batches flushed while the block streamed in (each stamped
         with the triggering in-block packet index)."""
+        tr = self.dispatcher.tracer
+        if tr is not None and tr.enabled:
+            with tr.layer("ingest", len(now)):
+                return self._ingest_packets(
+                    key, now, rel_ts, size, direction, ttl, winsize,
+                    flags_byte, proto, s_port, d_port, flow_id, fin)
+        return self._ingest_packets(
+            key, now, rel_ts, size, direction, ttl, winsize, flags_byte, proto,
+            s_port, d_port, flow_id, fin)
+
+    def _ingest_packets(
+        self, key, now, rel_ts, size, direction, ttl, winsize, flags_byte,
+        proto, s_port, d_port, flow_id, fin,
+    ) -> tuple[np.ndarray, np.ndarray, list[BatchRecord]]:
         now = np.asarray(now, np.float64)
         B = len(now)
         statuses = np.full(B, int(FlowStatus.TRACKED), np.uint8)
@@ -792,6 +875,13 @@ class StreamingRuntime:
 
     def poll(self, now: float) -> list[BatchRecord]:
         """Periodic maintenance: idle eviction + timeout flushes."""
+        tr = self.dispatcher.tracer
+        if tr is not None and tr.enabled:
+            with tr.layer("poll"):
+                return self._poll(now)
+        return self._poll(now)
+
+    def _poll(self, now: float) -> list[BatchRecord]:
         for slot in self.table.evict_idle(now):
             self.dispatcher.enqueue(slot, now)
         return self.dispatcher.maybe_flush(now)
@@ -850,6 +940,7 @@ class StreamingRuntime:
         new_disp.tracer = disp.tracer
         new_disp.drift = disp.drift
         new_disp.trace_pid = disp.trace_pid
+        table.tracer = old.tracer
         ready = []
         for s in np.nonzero(old.ctrl["state"] != 0)[0]:
             ns = move_slot(old, table, int(s))

@@ -286,6 +286,9 @@ class FlowTable:
         self._rebuild_at = int(n_buckets * rebuild_tombstone_frac)
 
         self._free = list(range(capacity - 1, -1, -1))  # pop() -> slot 0 first
+        # layer spans (repro.serve.obs.Tracer), injected by Observability;
+        # off by default: the untraced hot path pays one `is not None` test
+        self.tracer = None
 
     # -- hash index ----------------------------------------------------------
 
@@ -830,6 +833,20 @@ class FlowTable:
         an occupied slot), so processing the carve first preserves the
         scalar cadence.
         """
+        tr = self.tracer
+        if tr is not None and tr.enabled:
+            with tr.layer("observe", len(key)):
+                return self._observe_batch(
+                    key, t, rel_ts, size, direction, ttl, winsize,
+                    flags_byte, proto, s_port, d_port, flow_id, fin)
+        return self._observe_batch(
+            key, t, rel_ts, size, direction, ttl, winsize, flags_byte, proto,
+            s_port, d_port, flow_id, fin)
+
+    def _observe_batch(
+        self, key, t, rel_ts, size, direction, ttl, winsize, flags_byte,
+        proto, s_port, d_port, flow_id, fin,
+    ) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
         key = np.asarray(key, np.uint64)
         B = len(key)
         self.metrics.pkts_total += B
@@ -1023,6 +1040,10 @@ class FlowTable:
         under reuse it runs on the non-frozen remainder only."""
         B = len(key)
         m = self.metrics
+        tr = self.tracer
+        on = tr is not None and tr.enabled
+        if on:
+            sp = tr.layer("observe.partition").start()
         statuses = np.full(B, int(FlowStatus.TRACKED), np.uint8)
         slots_out = np.full(B, -1, np.int64)
         accumulated = np.zeros(B, bool)
@@ -1055,6 +1076,8 @@ class FlowTable:
             | (new_u[pinv] & (pos == firstpos[pinv]))
         # phase-1 membership: resident FIN-key packets before the first FIN
         in_prefix = (~new_u[pinv]) & has_fin_u[pinv] & (pos < finpos[pinv])
+        if on:
+            sp.stop(U)
 
         def fast_apply(fsel: np.ndarray, slot_of_key: np.ndarray) -> None:
             """Vectorized observe for packets with no structural effects.
@@ -1120,13 +1143,20 @@ class FlowTable:
                 statuses[trig] = int(FlowStatus.READY)
 
         # phase 1: pre-FIN prefixes of resident FIN-bearing keys
-        fast_apply(np.flatnonzero(in_prefix), uslot)
+        pre = np.flatnonzero(in_prefix)
+        if on and pre.size:
+            sp = tr.layer("observe.fast", pre.size).start()
+        fast_apply(pre, uslot)
+        if on and pre.size:
+            sp.stop()
 
         # phase 2: structural events in original packet order (bulk-convert
         # the scalar subset to python values once — ~10x cheaper than
         # per-field numpy scalar conversion inside the loop)
         sc = np.flatnonzero(in_scalar)
         if sc.size:
+            if on:
+                sp = tr.layer("observe.slow", sc.size).start()
             obs = self._observe1
             for i, k_, t_, rts, sz, dr, tl, ws, fb, pr, sp_, dp_, fl, fn in zip(
                 sc.tolist(), key[sc].tolist(), t[sc].tolist(),
@@ -1142,10 +1172,14 @@ class FlowTable:
                 statuses[i] = int(st)
                 slots_out[i] = sl
                 accumulated[i] = m.pkts_accumulated > a0
+            if on:
+                sp.stop()
 
         # phase 3: the fin-free bulk (now-allocated new keys re-resolved)
         bulk = ~(in_scalar | in_prefix)
         if bulk.any():
+            if on:
+                sp = tr.layer("observe.fast", B - sc.size - pre.size).start()
             slot_of_key = uslot
             if new_u.any() and not tight:
                 nk = np.flatnonzero(new_u & ~scalar_all_u)
@@ -1153,6 +1187,8 @@ class FlowTable:
                     slot_of_key = uslot.copy()
                     slot_of_key[nk] = self._probe_many(uk[nk])
             fast_apply(np.flatnonzero(bulk), slot_of_key)
+            if on:
+                sp.stop()
 
         return statuses, slots_out, accumulated
 

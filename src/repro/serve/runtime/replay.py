@@ -619,6 +619,10 @@ class _WorkerClock:
         # counts are integer adds, so all shards feed one tracker
         self.pid = pid
         self.tracer = tracer
+        if tracer is not None:
+            # sampled lifecycles close on this clock (`charge`), not at
+            # the dispatcher's resolve
+            tracer.replay_clock = True
         self.slo = slo
         self.stage_s = {"ingest": 0.0, "infer": 0.0, "flush": 0.0}
 

@@ -339,6 +339,7 @@ class ShardedRuntime:
         dn.tracer = d0.tracer
         dn.drift = d0.drift
         dn.trace_pid = self.n_shards - 1
+        self.shards[-1].table.tracer = self.shards[0].table.tracer
         # ... including latency-component recording (DESIGN.md §14.1):
         # an empty recorder with shard 0's sketch layout, so the fleet
         # merge keeps folding identically-configured sketches

@@ -497,9 +497,11 @@ def test_hot_swap_and_scale_out_carry_hooks(pipeline, pipeline_b):
     rt.hot_swap(pipeline_b, now=0.0)
     for w in rt.shards:
         assert w.dispatcher.tracer is obs.tracer
+        assert w.table.tracer is obs.tracer
         assert w.dispatcher.drift is obs.drift
     i = rt.add_worker()
     assert rt.shards[i].dispatcher.tracer is obs.tracer
+    assert rt.shards[i].table.tracer is obs.tracer
     assert rt.shards[i].dispatcher.trace_pid == i
 
 

@@ -12,7 +12,9 @@ The topology is described inside a fixture (never at import): only one
 process at a time may load the TPU library, so only the worker that runs
 this file loads it.
 """
+import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -105,6 +107,27 @@ def test_fused_forest_infer_compiles(compile_for, width):
         *_packets(compile_for), *_forest(compile_for, trees, depth, classes),
         plan=stats_plan(EVERY_FAMILY), depth=P, forest_depth=depth,
         interpret=False).compile())
+
+
+def test_fused_kernel_keeps_its_name_under_another_wrapper(compile_for):
+    """A device trace names the kernel by its instruction; the benchmark's
+    readers match ``%fused_forest_infer.N``. Renaming the jit wrapper must
+    not move it."""
+    trees, depth, classes = WIDTHS["app"]
+
+    @functools.partial(jax.jit, static_argnames=("plan", "depth",
+                                                 "forest_depth", "interpret"))
+    def renamed_entry(*a, **k):
+        return fused_forest_infer.__wrapped__(*a, **k)
+
+    text = renamed_entry.lower(
+        *_packets(compile_for), *_forest(compile_for, trees, depth, classes),
+        plan=stats_plan(EVERY_FAMILY[:6]), depth=P, forest_depth=depth,
+        interpret=False).compile().as_text()
+    calls = [ln for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert len(calls) == 1
+    assert re.match(r"\s*(ROOT )?%fused_forest_infer\.\d+ = ", calls[0])
 
 
 def test_fused_agg_infer_compiles(compile_for):
