@@ -765,31 +765,52 @@ class StreamingRuntime:
     def flush_timeout_s(self) -> float:
         return self.dispatcher.flush_timeout_s
 
-    def _sub_block_end(self, now: np.ndarray, lo: int) -> int:
+    def _sub_block_end(self, now: np.ndarray, lo: int, key: np.ndarray,
+                       direction: np.ndarray, fin: np.ndarray) -> int:
         """Largest `hi` such that no flush can trigger before packet hi-1.
 
-        A full flush needs the ready queue to reach `max_batch`, which takes
-        at least (max_batch - len(queue)) READY packets; a timeout flush
-        needs an arrival past head_ready + flush_timeout_s (head cannot get
-        older mid-block, and a flow enqueued at t[p] >= t[lo] cannot time
-        out before t[lo] + timeout does). Bounding sub-blocks this way pins
-        every flush — and its table side effects (`mark_predicted`
-        recycling) — to a sub-block's final packet, which is exactly where
-        the per-packet cadence applies them."""
+        A timeout flush needs an arrival past head_ready + flush_timeout_s
+        (head cannot get older mid-block, and a flow enqueued at t[p] >=
+        t[lo] cannot time out before t[lo] + timeout does). A full flush
+        needs `room = max_batch - len(queue)` more READY packets, and only
+        the packets that `FlowTable.ready_potential` marks can be READY:
+        the sub-block runs to the room-th marked packet. The plan is made
+        from the table as it stands at `lo`, and it holds over the whole
+        sub-block because nothing but observation touches the table there:
+        no flush fires before the sub-block's final packet. A range of at
+        most `room` packets needs no plan, and `room` packets hold at most
+        `room` marks, so no sub-block ends before `lo + room`.
+
+        Bounding sub-blocks this way pins every flush — and its table side
+        effects (`mark_predicted` recycling) — to a sub-block's final
+        packet, which is exactly where the per-packet cadence applies
+        them."""
         disp = self.dispatcher
         B = len(now)
-        hi = min(B, lo + (disp.max_batch - len(disp._queue)))
+        room = disp.max_batch - len(disp._queue)
         ref = disp._queue.head_ready() if len(disp._queue) else float(now[lo])
         k = _timeout_boundary(now, lo, B, ref, disp.flush_timeout_s)
-        end = max(lo + 1, min(hi, k + 1))
-        if end < B:
-            tr = disp.tracer
-            if tr is not None and tr.enabled:
-                # which bound cut the block here: a timeout boundary, or the
-                # ready queue's room before a full flush
-                tr.count("subblock.cut_timeout" if k + 1 <= hi
-                         else "subblock.cut_room")
-        return end
+        lim = min(B, k + 1)    # the timeout bound
+        hi = lim               # the room bound, if it cuts first
+        tr = disp.tracer
+        on = tr is not None and tr.enabled
+        if lim - lo > room:
+            if on:
+                sp = tr.layer("ingest.plan", lim - lo).start()
+            marked = np.flatnonzero(self.table.ready_potential(
+                key[lo:lim], direction[lo:lim], fin[lo:lim]))
+            if marked.size >= room:
+                hi = lo + int(marked[room - 1]) + 1
+            if on:
+                sp.stop()
+                tr.count("subblock.ready_potential",
+                         int(np.searchsorted(marked, hi - lo)))
+        if hi < B and on:
+            # which bound cut the block here: a timeout boundary, or the
+            # ready queue's room before a full flush
+            tr.count("subblock.cut_timeout" if hi == lim
+                     else "subblock.cut_room")
+        return hi
 
     def ingest_packets(
         self, key, now, rel_ts, size, direction, ttl, winsize, flags_byte,
@@ -830,7 +851,7 @@ class StreamingRuntime:
         recs: list[BatchRecord] = []
         lo = 0
         while lo < B:
-            hi = self._sub_block_end(now, lo)
+            hi = self._sub_block_end(now, lo, key, direction, fin)
             st, slots, acc = self.table.observe_batch(
                 key[lo:hi], now[lo:hi], rel_ts[lo:hi], size[lo:hi],
                 direction[lo:hi], ttl[lo:hi], winsize[lo:hi],

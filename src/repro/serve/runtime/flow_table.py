@@ -1192,6 +1192,80 @@ class FlowTable:
 
         return statuses, slots_out, accumulated
 
+    def ready_potential(self, key: np.ndarray, direction: np.ndarray,
+                        fin: np.ndarray) -> np.ndarray:
+        """Per-packet mask of the packets of a block that *can* make a flow
+        READY or READY_EOF when the block is observed in order from the
+        table as it stands, with no flush in between: for every key and
+        every prefix of the block, the key's marks in the prefix are at
+        least its READY and READY_EOF statuses there (DESIGN.md §7.2).
+
+        With a key's packets taken in block order, its *closing* packet is
+        the first at which its FIN mask (the slot's `fin_mask`, or 0 for a
+        key with no slot, OR the directions of its FINs so far) reads 3.
+        Marked, per key:
+
+        - the closing packet and every later one: a close turns an
+          accumulating flow READY_EOF, recycles a PREDICTED one, and the
+          key may then re-tenant and fill a fresh flow;
+        - an ACTIVE flow's (pkt_depth - count)-th packet, and a key with
+          no slot's pkt_depth-th: the one packet before the close at
+          which the flow can reach depth.
+
+        Before its closing packet a key is neither closed nor recycled, so
+        it holds at most one flow: an ACTIVE flow turns READY only at
+        depth, and READY and PREDICTED flows leave their state only by a
+        flush or a close. A key with no slot allocates at its first packet
+        that is not dropped (a dropped packet changes nothing, its FIN
+        included), so it reaches depth at its pkt_depth-th packet or
+        later, and a full table needs no mark of its own. Without a close
+        each key has at most one mark, the packet a READY status lands on
+        when nothing is dropped: the bound is exact for FIN-free traffic.
+        """
+        key = np.asarray(key, np.uint64)
+        n = len(key)
+        if n == 0:
+            return np.zeros(0, bool)
+        order = np.argsort(key)
+        ks = key[order]
+        head = np.empty(n, bool)
+        head[0] = True
+        np.not_equal(ks[1:], ks[:-1], out=head[1:])
+        # each key's packets back in block order: a plain sort of (key
+        # rank, position) costs less than a stable argsort of the keys
+        bits = n.bit_length()
+        order = np.sort(((np.cumsum(head) - 1) << bits) | order) \
+            & ((1 << bits) - 1)
+        start = np.flatnonzero(head)
+        end = np.append(start[1:], n)
+        slot = self._probe_many(ks[start])
+        res = slot >= 0
+        rs = slot[res]
+        depth_at = np.full(len(start), self.pkt_depth, np.int64)
+        depth_at[res] = np.where(
+            self.ctrl["state"][rs] == 1,
+            self.pkt_depth - self.ctrl["count"][rs].astype(np.int64), 0)
+        fmask = np.zeros(len(start), np.uint8)
+        fmask[res] = self.ctrl["fin_mask"][rs]
+
+        # the closing packet, in sorted positions (n: none): the later of
+        # the first FIN each direction still lacks
+        fin_s = np.asarray(fin, bool)[order]
+        dir_s = np.asarray(direction)[order] & 1
+        idx = np.arange(n)
+        closing = start
+        for bit in (0, 1):
+            first = np.minimum.reduceat(
+                np.where(fin_s & (dir_s == bit), idx, n), start)
+            closing = np.where(fmask & (1 << bit), closing,
+                               np.maximum(closing, first))
+        marks = idx >= np.repeat(np.minimum(closing, end), end - start)
+        reach = (depth_at > 0) & (depth_at <= end - start)
+        marks[start[reach] + depth_at[reach] - 1] = True
+        out = np.empty(n, bool)
+        out[order] = marks
+        return out
+
     # -- maintenance ---------------------------------------------------------
 
     def detach_slot(self, slot: int) -> None:

@@ -321,8 +321,9 @@ class ServiceModel:
         runtime = getattr(runtime, "shards", [runtime])[0]
         # -- ingest cost: run the actual vectorized observe_batch path
         # (the path the replay drives) on a scratch table, block by block.
-        # The default block matches the flush-bounded sub-blocks
-        # (~max_batch) the runtime actually feeds it at measured rates.
+        # The default block is short: the runtime's sub-blocks end only
+        # where a flush can fire, so they are as long or longer, and a
+        # short block charges ingest its fixed per-call cost generously.
         # Mirrors the runtime table's reuse layout so aggregate-update
         # work is part of the charged per-packet cost when reuse is on.
         rtab = runtime.table

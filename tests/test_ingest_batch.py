@@ -12,16 +12,24 @@ the scalar max-chain.
 import numpy as np
 import pytest
 
+try:
+    from hypothesis import given, settings, strategies as hst
+except ImportError:  # seeded-sampling fallback, see tests/_hypothesis_shim.py
+    from _hypothesis_shim import given, settings, strategies as hst
+
 from repro.core.search_space import FeatureRep
 from repro.serve.runtime import (
     FlowStatus,
     FlowTable,
     PacketStream,
+    ReuseConfig,
     RuntimeMetrics,
     ServiceModel,
+    ShardedRuntime,
     StreamingRuntime,
     replay,
 )
+from repro.serve.runtime.dispatch import _timeout_boundary
 from repro.traffic import extract_features, make_dataset
 from repro.traffic.models import train_traffic_model
 from repro.traffic.pipeline import build_pipeline
@@ -424,3 +432,311 @@ def test_arena_rotation_protects_pending_batches(pipeline, stream, ds):
     batch_preds = pipeline(ds.truncate(DEPTH))
     stream_preds = np.array([stats.predictions[i] for i in range(ds.n_flows)])
     assert (stream_preds == batch_preds).all()
+
+
+# ---------------------------------------------------------------------------
+# sub-block cuts at the READY packets that can fill the queue
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def plain_pipeline(ds):
+    # incremental features (reuse can engage) and no Pallas kernel: these
+    # tests run many flushes, and what they check is the cadence
+    rep = FeatureRep(("dur", "s_load", "s_bytes_mean", "s_iat_mean",
+                      "ack_cnt"), depth=DEPTH)
+    X = extract_features(ds, rep.features, rep.depth)
+    forest, _ = train_traffic_model(X, ds.label, model="tree-fast", seed=0)
+    return build_pipeline(rep, forest, max_pkts=rep.depth, use_kernel=False)
+
+
+def _flows(seed, *, n_flows, mean_len, n_keys=None, both_fin=0.0,
+           one_fin=0.0, trail=0, span=2.0):
+    """Interleaved packets of `n_flows` flows, delivery-ordered, as the
+    keyword arguments of `ingest_packets`. Flow i uses key i % n_keys (so
+    keys are reused by later flows); a `both_fin` share ends with a FIN
+    each way and a `one_fin` share with one FIN, followed by `trail` more
+    packets of the same key."""
+    rng = np.random.default_rng(seed)
+    n_keys = n_keys or n_flows
+    keys = rng.integers(1, 2 ** 63, n_keys).astype(np.uint64)
+    start = np.sort(rng.random(n_flows)) * span
+    cols = {c: [] for c in ("key", "t", "rel", "dir", "fin", "fid")}
+    for f in range(n_flows):
+        n = 1 + int(rng.exponential(mean_len))
+        u = rng.random()
+        fin = np.zeros(n, bool)
+        dirn = rng.integers(0, 2, n)
+        if u < both_fin and n >= 2:
+            fin[-2:] = True
+            dirn[-2:] = rng.permutation(2)
+        elif u < both_fin + one_fin:
+            fin[-1] = True
+        if fin.any() and trail:
+            fin = np.append(fin, np.zeros(trail, bool))
+            dirn = np.append(dirn, rng.integers(0, 2, trail))
+            n += trail
+        rel = np.cumsum(rng.exponential(span / 50, n))
+        cols["key"].append(np.full(n, keys[f % n_keys]))
+        cols["t"].append(start[f] + rel)
+        cols["rel"].append(rel - rel[0])
+        cols["dir"].append(dirn)
+        cols["fin"].append(fin)
+        cols["fid"].append(np.full(n, f))
+    c = {k: np.concatenate(v) for k, v in cols.items()}
+    o = np.argsort(c["t"], kind="stable")
+    E = o.size
+    return dict(
+        key=c["key"][o], now=c["t"][o], rel_ts=c["rel"][o].astype(np.float32),
+        size=rng.integers(40, 1500, E).astype(np.float32),
+        direction=c["dir"][o].astype(np.uint8),
+        ttl=rng.integers(30, 128, E).astype(np.float32),
+        winsize=rng.integers(0, 65535, E).astype(np.float32),
+        flags_byte=rng.integers(0, 256, E).astype(np.uint8),
+        proto=np.full(E, 6.0, np.float32),
+        s_port=(c["fid"][o] % 50000 + 1024).astype(np.float32),
+        d_port=np.full(E, 443.0, np.float32),
+        flow_id=c["fid"][o].astype(np.int64), fin=c["fin"][o],
+    )
+
+
+_COLS = ("key", "now", "rel_ts", "size", "direction", "ttl", "winsize",
+         "flags_byte", "proto", "s_port", "d_port", "flow_id", "fin")
+
+
+def _feed(rt, pk, block, lo=0, hi=None):
+    """Ingest packets [lo, hi) in blocks of `block` (0: per packet, through
+    `ingest_packet`); returns the per-packet statuses."""
+    hi = len(pk["now"]) if hi is None else hi
+    if block == 0:
+        st = np.empty(hi - lo, np.uint8)
+        for i in range(lo, hi):
+            s, _ = rt.ingest_packet(
+                int(pk["key"][i]), float(pk["now"][i]), float(pk["rel_ts"][i]),
+                float(pk["size"][i]), int(pk["direction"][i]),
+                float(pk["ttl"][i]), float(pk["winsize"][i]),
+                int(pk["flags_byte"][i]), float(pk["proto"][i]),
+                float(pk["s_port"][i]), float(pk["d_port"][i]),
+                int(pk["flow_id"][i]), bool(pk["fin"][i]))
+            st[i - lo] = int(s)
+        return st
+    out = []
+    for b in range(lo, hi, block):
+        e = min(b + block, hi)
+        st, _, _ = rt.ingest_packets(*(pk[c][b:e] for c in _COLS))
+        out.append(st)
+    return np.concatenate(out)
+
+
+def _records(recs):
+    return [(r.flow_ids.tolist(), r.ready_ts.tolist(), r.flush_ts, r.bucket,
+             r.n_real, r.reason) for r in recs]
+
+
+def _assert_same_runtime(want: StreamingRuntime, got: StreamingRuntime):
+    if want.table.reuse:
+        want.table.flush_agg()
+        got.table.flush_agg()
+    assert _records(want.dispatcher.records) == _records(got.dispatcher.records)
+    assert want.results.keys() == got.results.keys()
+    for k, v in want.results.items():
+        assert np.array_equal(v, got.results[k]), k
+    _assert_tables_equal(want.table, got.table)
+
+
+class _Spy(StreamingRuntime):
+    """Counts `observe_batch` calls and the sub-block ends that the timeout
+    bound set, and checks the invariant of the cut: every flush fires at
+    the final packet of a sub-block."""
+
+    def __init__(self, *a, **k):
+        super().__init__(*a, **k)
+        self.calls = 0
+        self.timeout_cuts = 0
+        self.ends: set = set()
+        inner = self.table.observe_batch
+
+        def counted(*args):
+            self.calls += 1
+            return inner(*args)
+
+        self.table.observe_batch = counted
+
+    def _ingest_packets(self, *cols):
+        self.ends = set()
+        statuses, acc, recs = super()._ingest_packets(*cols)
+        for r in recs:
+            if r.reason != "refresh":
+                assert r.flush_idx in self.ends, "a flush inside a sub-block"
+        return statuses, acc, recs
+
+    def _sub_block_end(self, now, lo, key, direction, fin):
+        hi = super()._sub_block_end(now, lo, key, direction, fin)
+        self.ends.add(hi - 1)
+        disp = self.dispatcher
+        ref = disp._queue.head_ready() if len(disp._queue) else float(now[lo])
+        if hi < len(now) and hi == _timeout_boundary(
+                now, lo, len(now), ref, disp.flush_timeout_s) + 1:
+            self.timeout_cuts += 1
+        return hi
+
+
+_CADENCE_CASES = {
+    # long blocks, ~1 READY packet in 60: a handful of flushes a block
+    "saturated": dict(
+        flows=dict(n_flows=500, mean_len=60), block=4096,
+        rt=dict(capacity=1024, max_batch=32, flush_timeout_s=10.0)),
+    # closes from both sides, then the key returns (re-tenancy) after the
+    # mid-block flush that retires the first tenancy
+    "fin_both_sides_retenancy": dict(
+        flows=dict(n_flows=400, mean_len=12, n_keys=150, both_fin=0.6,
+                   one_fin=0.2, trail=3), block=1024,
+        rt=dict(capacity=1024, max_batch=16, flush_timeout_s=0.05)),
+    # flows reach depth, sit in the queue, close there (fin_mask == 3 while
+    # READY), and get more packets after the flush that recycles them
+    "closed_while_queued": dict(
+        flows=dict(n_flows=300, mean_len=4, both_fin=0.9, trail=6),
+        block=2048, rt=dict(capacity=1024, max_batch=64,
+                            flush_timeout_s=10.0)),
+    # a table far smaller than the live set: drops, and recycling that
+    # frees slots mid-block
+    "table_pressure": dict(
+        flows=dict(n_flows=300, mean_len=15, n_keys=200, both_fin=0.5,
+                   one_fin=0.3, trail=2), block=512,
+        rt=dict(capacity=24, max_batch=8, flush_timeout_s=0.02)),
+    # one-packet flows at depth 1: every packet is READY, so the plan
+    # marks every packet and a block of room + 1 must be cut at the room-th
+    "every_packet_ready": dict(
+        flows=dict(n_flows=300, mean_len=0), block=9,
+        rt=dict(capacity=64, max_batch=8, flush_timeout_s=10.0,
+                pkt_depth=1, execute=False)),
+    # reuse on: PREDICTED flows take the frozen fast path
+    "reuse": dict(
+        flows=dict(n_flows=300, mean_len=40, n_keys=250, both_fin=0.3,
+                   one_fin=0.3, trail=2), block=2048,
+        rt=dict(capacity=1024, max_batch=16, flush_timeout_s=0.05,
+                reuse=ReuseConfig(drift_threshold=0.05,
+                                  refresh_every=10 ** 6))),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_CADENCE_CASES))
+def test_block_ingest_matches_per_packet_cadence(plain_pipeline, case):
+    """Block ingest under the READY-potential cut gives the per-packet
+    cadence's statuses, flushes, classes, table and metrics, exactly."""
+    c = _CADENCE_CASES[case]
+    pk = _flows(11, **c["flows"])
+    runs = {}
+    for block in (0, c["block"]):
+        rt = _Spy(plain_pipeline, min_bucket=8, **c["rt"])
+        st = _feed(rt, pk, block)
+        rt.drain(float(pk["now"][-1]) + 1.0)
+        runs[block] = (st, rt)
+    (st_w, want), (st_g, got) = runs[0], runs[c["block"]]
+    assert (st_w == st_g).all()
+    _assert_same_runtime(want, got)
+    n_ready = int(np.isin(st_w, [int(FlowStatus.READY),
+                                 int(FlowStatus.READY_EOF)]).sum())
+    assert want.metrics.batches > 2 and n_ready > 0
+    if case == "table_pressure":
+        assert want.metrics.drops_table > 0
+    if case in ("fin_both_sides_retenancy", "closed_while_queued"):
+        assert (st_w == int(FlowStatus.CLOSED)).any() or \
+            want.metrics.slots_recycled > 0
+    if case == "reuse":
+        assert got.last_frozen_mask is not None
+
+
+def test_sharded_block_ingest_matches_per_packet_cadence(plain_pipeline):
+    """Each shard's block ingest under the new cut equals its per-packet
+    cadence on the packets steered to it."""
+    pk = _flows(12, n_flows=400, mean_len=20, n_keys=300, both_fin=0.4,
+                one_fin=0.3, trail=2)
+    shard = (pk["key"] % np.uint64(3)).astype(np.int64)
+    srt = ShardedRuntime(plain_pipeline, n_shards=3, capacity=300,
+                         max_batch=16, min_bucket=8, flush_timeout_s=0.05)
+    srt.shards = [_Spy(plain_pipeline, **srt._worker_kwargs)
+                  for _ in srt.shards]
+    for lo in range(0, len(pk["now"]), 1024):
+        hi = min(lo + 1024, len(pk["now"]))
+        srt.ingest_packets(*(pk[c][lo:hi] for c in _COLS), shard=shard[lo:hi])
+    end = float(pk["now"][-1]) + 1.0
+    srt.drain(end)
+    for i, got in enumerate(srt.shards):
+        want = StreamingRuntime(plain_pipeline, capacity=100, max_batch=16,
+                                min_bucket=8, flush_timeout_s=0.05)
+        sub = {c: pk[c][shard == i] for c in _COLS}
+        _feed(want, sub, 0)
+        want.drain(end)
+        assert want.metrics.batches > 2
+        _assert_same_runtime(want, got)
+
+
+class _RoomCut(_Spy):
+    """The former bound: at most `max_batch - len(queue)` packets a
+    sub-block, as if every packet could make a flow READY."""
+
+    def _sub_block_end(self, now, lo, key, direction, fin):
+        disp = self.dispatcher
+        B = len(now)
+        ref = disp._queue.head_ready() if len(disp._queue) else float(now[lo])
+        k = _timeout_boundary(now, lo, B, ref, disp.flush_timeout_s)
+        hi = min(B, lo + disp.max_batch - len(disp._queue), k + 1)
+        self.ends.add(hi - 1)
+        return hi
+
+
+def test_fin_free_active_blocks_cut_only_at_flushes(plain_pipeline):
+    """FIN-free traffic on ACTIVE flows: the plan is exact, so a block makes
+    at most one `observe_batch` call, plus one per flush and per timeout
+    cut, where the former bound made one per `max_batch` packets."""
+    pk = _flows(13, n_flows=600, mean_len=80, span=4.0)
+    # seed the table: every flow's first packet, outside the spied blocks
+    first = np.unique(pk["flow_id"], return_index=True)[1]
+    rest = np.setdiff1d(np.arange(len(pk["now"])), first)
+    seed = {c: pk[c][np.sort(first)] for c in _COLS}
+    body = {c: pk[c][rest] for c in _COLS}
+    rt = _Spy(plain_pipeline, capacity=1024, max_batch=32, min_bucket=8,
+              flush_timeout_s=1.0, execute=False)
+    _feed(rt, seed, 1 << 20)
+    n_blocks = 0
+    for lo in range(0, len(body["now"]), 4096):
+        hi = min(lo + 4096, len(body["now"]))
+        calls0, cuts0, recs0 = rt.calls, rt.timeout_cuts, \
+            len(rt.dispatcher.records)
+        _feed(rt, body, 4096, lo, hi)
+        flushes = len(rt.dispatcher.records) - recs0
+        assert rt.calls - calls0 <= 1 + flushes + rt.timeout_cuts - cuts0
+        n_blocks += 1
+    assert rt.metrics.flushes_full > n_blocks   # the queue filled mid-block
+    assert rt.calls < len(body["now"]) / 32
+
+
+@settings(max_examples=12, deadline=None)
+@given(seed=hst.integers(min_value=0, max_value=2 ** 31 - 1))
+def test_ready_cut_never_makes_more_calls_than_the_room_cut(plain_pipeline,
+                                                            seed):
+    """On random traffic (closes, re-tenancy, drops, timeouts), the new cut
+    gives the former bound's outcome with no more `observe_batch` calls."""
+    rng = np.random.default_rng(seed)
+    pk = _flows(seed, n_flows=int(rng.integers(20, 200)),
+                mean_len=float(rng.uniform(2, 30)),
+                n_keys=int(rng.integers(10, 200)),
+                both_fin=float(rng.uniform(0, 0.7)),
+                one_fin=float(rng.uniform(0, 0.3)),
+                trail=int(rng.integers(0, 4)),
+                span=float(rng.uniform(0.05, 2.0)))
+    kw = dict(capacity=int(rng.integers(8, 256)),
+              max_batch=int(2 ** rng.integers(1, 6)), min_bucket=2,
+              flush_timeout_s=float(rng.choice([0.005, 0.05, 10.0])),
+              execute=False)
+    block = int(rng.integers(16, 2048))
+    runs = []
+    for cls in (_RoomCut, _Spy):
+        rt = cls(plain_pipeline, **kw)
+        statuses = _feed(rt, pk, block)
+        rt.drain(float(pk["now"][-1]) + 1.0)
+        runs.append((statuses, rt))
+    (st_old, old), (st_new, new) = runs
+    assert (st_old == st_new).all()
+    _assert_same_runtime(old, new)
+    assert new.calls <= old.calls

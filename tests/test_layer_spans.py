@@ -47,28 +47,38 @@ def _block(stream, lo, hi):
             stream.s_port[fid], stream.d_port[fid], fid, stream.fin[lo:hi])
 
 
-def _runtime(pipeline, tracer=None):
-    # a small table and short batches: sub-blocks end at both bounds,
-    # flows are dropped, and the pending window resolves mid-block
-    rt = StreamingRuntime(pipeline, capacity=96, max_batch=16, min_bucket=8,
-                          flush_timeout_s=0.02)
+def _runtime(pipeline, tracer=None, **kw):
+    # a small table and short batches: flows are dropped, and the pending
+    # window resolves mid-block
+    kw = {"capacity": 96, "max_batch": 16, "min_bucket": 8,
+          "flush_timeout_s": 0.02, **kw}
+    rt = StreamingRuntime(pipeline, **kw)
     if tracer is not None:
         Observability(tracer=tracer).attach(rt)
     return rt
 
 
-def _ingest(rt, stream, poll_every=4):
-    """Feed the stream in blocks; returns its last time and the number of
-    packets that made a flow READY."""
+# a table that drops nothing, 8-flow batches and the stream's clock run
+# 50x faster: the queue fills before the flush timeout often enough that
+# sub-blocks end at both bounds, and the READY plan runs
+FAST = dict(capacity=1024, max_batch=8)
+SPEED = 50.0
+
+
+def _ingest(rt, stream, poll_every=4, speed=1.0):
+    """Feed the stream in blocks, its clock `speed` times faster; returns
+    its last time and the number of packets that made a flow READY."""
     E = stream.n_events
     n_ready = 0
     for k, lo in enumerate(range(0, E, BLOCK)):
         hi = min(lo + BLOCK, E)
-        st, _, _ = rt.ingest_packets(*_block(stream, lo, hi))
+        blk = list(_block(stream, lo, hi))
+        blk[1] = blk[1] / speed
+        st, _, _ = rt.ingest_packets(*blk)
         n_ready += int(np.isin(st, READY).sum())
         if k % poll_every == poll_every - 1:
-            rt.poll(float(stream.base_t[hi - 1]))
-    return float(stream.base_t[E - 1]), n_ready
+            rt.poll(float(stream.base_t[hi - 1]) / speed)
+    return float(stream.base_t[E - 1]) / speed, n_ready
 
 
 def test_layer_items_match_the_runtime_counters(pipeline, stream):
@@ -125,13 +135,49 @@ def test_self_time_nests(pipeline, stream):
 def test_subblock_cuts_count_every_sub_block_but_the_last(pipeline, stream):
     _, st = stream
     tr = Tracer(sample=0.0)
-    rt = _runtime(pipeline, tr)
-    _ingest(rt, st, poll_every=10 ** 9)
+    rt = _runtime(pipeline, tr, **FAST)
+    _ingest(rt, st, poll_every=10 ** 9, speed=SPEED)
     lay = tr.layers()
     c = lay["counters"]
     assert c["subblock.cut_room"] > 0 and c["subblock.cut_timeout"] > 0
     assert c["subblock.cut_room"] + c["subblock.cut_timeout"] == \
         lay["spans"]["observe"]["calls"] - lay["spans"]["ingest"]["calls"]
+
+
+def test_plan_span_and_ready_potential_counter(pipeline, stream):
+    """`ingest.plan` times each READY plan under `ingest`, and
+    `subblock.ready_potential` counts the packets it marked in the
+    sub-blocks it bounded: at least the READY packets there."""
+    _, st = stream
+    tr = Tracer(sample=0.0)
+    rt = _runtime(pipeline, tr, **FAST)
+    planned = []
+    end = type(rt)._sub_block_end
+
+    def calls():
+        return tr.layers()["spans"].get("ingest.plan", {"calls": 0})["calls"]
+
+    def spy(self, now, lo, *cols):
+        n = calls()
+        hi = end(self, now, lo, *cols)
+        if calls() > n:
+            planned.append((lo, hi))
+        return hi
+
+    rt._sub_block_end = spy.__get__(rt)
+    n_ready = 0
+    for lo in range(0, st.n_events, BLOCK):
+        blk = list(_block(st, lo, min(lo + BLOCK, st.n_events)))
+        blk[1] = blk[1] / SPEED
+        del planned[:]
+        ready = np.isin(rt.ingest_packets(*blk)[0], READY)
+        n_ready += sum(int(ready[a:b].sum()) for a, b in planned)
+    lay = tr.layers()
+    c = lay["counters"]
+    assert lay["spans"]["ingest.plan"]["calls"] >= c["subblock.cut_room"] > 0
+    assert c["subblock.ready_potential"] >= n_ready > 0
+    assert lay["spans"]["ingest.plan"]["total_ns"] <= \
+        lay["spans"]["ingest"]["total_ns"]
 
 
 def _outcome(rt):
@@ -162,8 +208,9 @@ def test_disabled_tracer_opens_no_span(pipeline, stream, monkeypatch):
     tr = Tracer(enabled=False)
     monkeypatch.setattr(tr, "layer", refuse)
     monkeypatch.setattr(tr, "count", refuse)
-    rt = _runtime(pipeline, tr)
-    rt.drain(_ingest(rt, st)[0] + 1.0)
+    for kw, speed in (({}, 1.0), (FAST, SPEED)):   # the second plans
+        rt = _runtime(pipeline, tr, **kw)
+        rt.drain(_ingest(rt, st, speed=speed)[0] + 1.0)
     assert tr.layers() == {"spans": {}, "counters": {}}
 
 
