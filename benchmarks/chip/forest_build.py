@@ -24,13 +24,14 @@ def _thr32(t: np.ndarray) -> np.ndarray:
     return t32
 
 
-def dense_tree(tree, depth: int, n_classes: int, classes: np.ndarray):
-    """One fitted sklearn tree -> (feature (2**D-1,), threshold, leaf (2**D, K))."""
+def dense_tree(tree, depth: int, classes: np.ndarray, feat, thr, leaf) -> None:
+    """Write one fitted sklearn tree into its rows of the forest's arrays:
+    `feat` and `thr` of shape (2**D-1,), every slot written, and `leaf` of
+    shape (2**D, K), zero on entry (only the fitted classes' columns are
+    written)."""
     left, right = tree.children_left, tree.children_right
     value = tree.value[:, 0, :].astype(np.float64)
     value = value / np.maximum(value.sum(axis=1, keepdims=True), 1e-300)
-    feat = np.zeros(2 ** depth - 1, np.int32)
-    thr = np.full(2 ** depth - 1, np.inf, np.float32)
     cur = np.zeros(1, np.int64)
     for lvl in range(depth):
         base = 2 ** lvl - 1
@@ -42,16 +43,13 @@ def dense_tree(tree, depth: int, n_classes: int, classes: np.ndarray):
         nxt[0::2] = np.where(is_leaf, cur, left[cur])
         nxt[1::2] = np.where(is_leaf, cur, right[cur])
         cur = nxt
-    leaf = np.zeros((2 ** depth, n_classes), np.float32)
     leaf[:, classes] = value[cur]
-    return feat, thr, leaf
 
 
-def grow(x: np.ndarray, y: np.ndarray, *, n_trees: int, depth: int,
-         n_classes: int, seed: int):
-    """Fit `n_trees` CART trees of `max_depth` `depth` (one tree: no bootstrap,
-    every feature; several: a random forest) and return the dense arrays
-    (feature (T, 2**D-1) int32, threshold float32, leaf (T, 2**D, K) float32)."""
+def fit(x: np.ndarray, y: np.ndarray, *, n_trees: int, depth: int, seed: int) -> list:
+    """The fitted sklearn trees (`tree_` objects): `n_trees` CART trees of
+    `max_depth` `depth` (one tree: no bootstrap, every feature; several: a
+    random forest)."""
     from sklearn.ensemble import RandomForestClassifier
     from sklearn.tree import DecisionTreeClassifier
 
@@ -61,8 +59,21 @@ def grow(x: np.ndarray, y: np.ndarray, *, n_trees: int, depth: int,
     else:
         est = RandomForestClassifier(n_estimators=n_trees, max_depth=depth,
                                      random_state=rs, n_jobs=4).fit(x, y).estimators_
+    return [e.tree_ for e in est]
+
+
+def grow(x: np.ndarray, y: np.ndarray, *, n_trees: int, depth: int,
+         n_classes: int, seed: int):
+    """Fit the forest (`fit`) and return its dense arrays (feature
+    (T, 2**D-1) int32, threshold float32, leaf (T, 2**D, K) float32), each
+    tree written into its row: the host holds one forest, not a copy of it."""
+    trees = fit(x, y, n_trees=n_trees, depth=depth, seed=seed)
+    T = len(trees)
+    feat = np.zeros((T, 2 ** depth - 1), np.int32)
+    thr = np.full((T, 2 ** depth - 1), np.inf, np.float32)
+    leaf = np.zeros((T, 2 ** depth, n_classes), np.float32)
     # a forest's trees see class indices 0..k-1 of the fitted classes_
     classes = np.unique(y)
-    parts = [dense_tree(e.tree_, depth, n_classes, classes) for e in est]
-    return (np.stack([p[0] for p in parts]), np.stack([p[1] for p in parts]),
-            np.stack([p[2] for p in parts]))
+    for t, tree in enumerate(trees):
+        dense_tree(tree, depth, classes, feat[t], thr[t], leaf[t])
+    return feat, thr, leaf

@@ -12,8 +12,10 @@ message sizes and think-times, lognormal sizes and inter-arrivals, per-class
 TTLs, windows and ports, FIN on the last packet of ~80% of flows). These
 shapes are assumed, not fitted to a published trace: `shape_stats` reports
 what they give. Class parameters come from the configuration's fixed
-``class_seed``, so every run serves the same device population; the run's
-seed draws the flows, their start times and their 5-tuple keys.
+``class_seed``, so every run serves the same device population; the template
+pool and the flow starts come from its fixed ``tap_seed``, so every run
+offers the same work. The run's seed orders the templates over the starts
+and draws the 5-tuple keys (and, in the harness, the forest).
 
 A tap at R packets/s holds every flow that is alive, so flows keep their own
 timestamps: flows start as a Poisson process (or the mix's two-state MMPP) at
@@ -285,9 +287,14 @@ class Tap:
                 self.flow_id[s], self.fin[s])
 
 
-def build_tap(tm: Templates, *, seed: int, pps: float, seconds: float,
-              mix: dict, depth: int) -> Tap:
+def build_tap(tm: Templates, *, seed: int, tap_seed: int, pps: float,
+              seconds: float, mix: dict, depth: int) -> Tap:
     """Tile the template pool into the packet stream of one run.
+
+    Every run seed gets the same work in another order: the flow starts are
+    drawn from `tap_seed`, every template row starts the same number of
+    times (the first ``n mod pool`` rows once more), and `seed` only
+    decides which template starts when and draws the keys.
 
     Flows start over ``[t0 - prefill_s, t0 + seconds)``; a packet is due at
     its flow's start plus its flow-relative timestamp, and only packets due
@@ -298,20 +305,23 @@ def build_tap(tm: Templates, *, seed: int, pps: float, seconds: float,
     only touch counters. This keeps the prefill short without changing what
     the table holds when the window starts.
     """
-    rng = seed_rng(seed, 2)
     mean_pkts = float(tm.flow_len.mean())
     rate = pps / mean_pkts
     prefill = float(mix["prefill_s"])
     t0 = prefill
-    starts = flow_starts(rng, rate, 0.0, t0 + seconds, mix, t0)
+    starts = flow_starts(seed_rng(tap_seed, 2), rate, 0.0, t0 + seconds, mix, t0)
     n_inst = len(starts)
-    tmpl = rng.integers(0, tm.n_flows, n_inst).astype(np.int32)
+    tmpl = seed_rng(seed, 2).permutation(
+        np.resize(np.arange(tm.n_flows, dtype=np.int32), n_inst))
     lens = tm.flow_len[tmpl].astype(np.int64)
     inst = np.repeat(np.arange(n_inst, dtype=np.int32), lens)
+    P = tm.ts.shape[1]
+    # each packet's flat index into the (n_flows, P) template arrays:
+    # its template row times P plus its index in the flow
     first = np.cumsum(lens) - lens
-    pidx = (np.arange(lens.sum(), dtype=np.int64) - np.repeat(first, lens)).astype(np.int16)
-    trow = tmpl[inst]
-    due = starts[inst] + tm.ts[trow, pidx].astype(np.float64)
+    flat = np.arange(lens.sum(), dtype=np.int64) - np.repeat(first - tmpl * np.int64(P), lens)
+    pidx = flat % P
+    due = starts[inst] + tm.ts.ravel()[flat]
     keep = due < t0 + seconds
     # before t0: the first `depth` packets and the last one before t0
     pre = due < t0
@@ -319,18 +329,37 @@ def build_tap(tm: Templates, *, seed: int, pps: float, seconds: float,
     nxt_pre[:-1] = pre[1:] & (inst[1:] == inst[:-1])
     last_pre = pre & ~nxt_pre
     keep &= ~pre | (pidx < depth) | last_pre
-    inst, pidx, trow, due = inst[keep], pidx[keep], trow[keep], due[keep]
-    order = np.argsort(due, kind="stable")
-    inst, pidx, trow, due = inst[order], pidx[order], trow[order], due[order]
-    fb = tm.flags[trow, pidx]
+    kept = np.flatnonzero(keep)
+    order, due = stable_sort(due[kept])
+    kept = kept[order]
+    inst, flat = inst[kept], flat[kept]
+    pidx, trow = (flat % P).astype(np.int16), flat // P
+    fb = tm.flags.ravel()[flat]
     keys = flow_keys(seed, n_inst)
     return Tap(
         due=due, inst=inst, pidx=pidx, key=keys[inst],
-        rel_ts=tm.ts[trow, pidx], size=tm.size[trow, pidx],
-        direction=tm.direction[trow, pidx], ttl=tm.ttl[trow, pidx],
-        winsize=tm.winsize[trow, pidx], flags=fb,
+        rel_ts=tm.ts.ravel()[flat], size=tm.size.ravel()[flat],
+        direction=tm.direction.ravel()[flat], ttl=tm.ttl.ravel()[flat],
+        winsize=tm.winsize.ravel()[flat], flags=fb,
         proto=tm.proto[trow], s_port=tm.s_port[trow], d_port=tm.d_port[trow],
         flow_id=inst, fin=(fb >> _F["fin"]) & 1 > 0,
         tmpl=tmpl, start=starts, t0=t0,
         n_prefill=int(np.searchsorted(due, t0, side="left")),
     )
+
+
+def stable_sort(x: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
+    """``np.argsort(x, kind="stable")`` and `x` in that order, by a faster
+    unstable sort whose runs of equal values are then put back in index
+    order."""
+    order = np.argsort(x)
+    s = x[order]
+    tie = s[1:] == s[:-1]
+    if tie.any():
+        in_run = np.zeros(len(x), bool)
+        in_run[1:] |= tie
+        in_run[:-1] |= tie
+        at = np.flatnonzero(in_run)
+        sub = order[at]
+        order[at] = sub[np.lexsort((sub, s[at]))]
+    return order, s

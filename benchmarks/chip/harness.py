@@ -12,7 +12,9 @@ the run's flows), fed through ``ingest_packets`` and ``poll``. The benchmark
 makes the traffic, the forest and the reference itself (`gen`,
 `forest_build`, `reference`); it wraps four methods of the runtime's
 instances from here to time them and to keep the probabilities the timed
-path produced.
+path produced. A traced run also attaches the program's own layer tracer
+(`repro.serve.obs.Tracer`) over the window and keeps its layer table, so a
+reader can take a metric from the program's ``cato.*`` spans and counters.
 """
 from __future__ import annotations
 
@@ -150,6 +152,7 @@ class Result:
         self.submits: list = []
         self.trace = None
         self.trace_window = None
+        self.program = None         # the program's layer table, traced runs only
         self.shape = None
         self.peak = None
         self.kernel_match = ()
@@ -195,11 +198,13 @@ class CacheEvents:
 
 
 def build_forest(cfg: dict, seed: int):
-    """Template pool, training flows and the grown forest of one run."""
+    """Template pool (the config's `tap_seed`), training flows and the
+    grown forest (the run's seed) of one run."""
     feats = sorted(cfg["features"])
     P = int(cfg["packet_depth"])
     pool = gen.make_templates(cfg["use_case"], int(cfg["pool_flows"]),
-                              gen.seed_rng(seed, 1), int(cfg["class_seed"]))
+                              gen.seed_rng(int(cfg["tap_seed"]), 1),
+                              int(cfg["class_seed"]))
     tr = gen.make_templates(cfg["use_case"], int(cfg["train_flows"]),
                             gen.seed_rng(seed, 3), int(cfg["class_seed"]))
     x = window_features(feats, tr, np.arange(tr.n_flows),
@@ -292,12 +297,15 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool, *,
         pps: float | None = None, require_tpu: bool = True,
         control: bool = False, make_pipeline=default_pipeline,
         fault=None, log=print, overrides: dict | None = None,
-        keep_trace: str | None = None) -> dict:
-    """One run of one cell; returns the result line as a dict.
+        keep_trace: str | None = None,
+        program_layers: bool = False) -> tuple[dict, Result]:
+    """One run of one cell: the result line as a dict, and the `Result`
+    its metrics were read from.
 
     `pps` overrides the mix's rate (the knee sweep); `keep_trace` writes
-    the first 40 ms of the traced window, with what the reducers read from
-    it, as a test fixture. `make_pipeline`,
+    100 ms of the traced window, with what the reducers read from it, as a
+    test fixture; `program_layers` attaches the program's layer tracer in
+    an untraced run too (`layer_probe.py`, to price the tracer). `make_pipeline`,
     `fault` and `overrides` exist for the tests, which drive a run on the
     CPU: `fault(rt)` may break the timed path after set-up, and `overrides`
     replaces configuration or mix keys (smaller pools, a shorter prefill).
@@ -330,8 +338,8 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool, *,
     feats, pool, forest = build_forest(cfg, seed)
     marks.append(("forest", time.time() - t_proc))
     offered = float(pps) if pps else float(mix["rate_x_knee"]) * float(cfg["knee_pps"])
-    tap = gen.build_tap(pool, seed=seed, pps=offered, seconds=seconds, mix=mix,
-                        depth=P)
+    tap = gen.build_tap(pool, seed=seed, tap_seed=int(cfg["tap_seed"]), pps=offered,
+                        seconds=seconds, mix=mix, depth=P)
     marks.append(("traffic", time.time() - t_proc))
     pipe = make_pipeline(feats, forest, cfg)
     n_inst = len(tap.start)
@@ -360,6 +368,13 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool, *,
     spans.wrap(pipe, "finalize", "resolve")
     spans.wrap(disp, "gather", "gather")
     spans.wrap(rt.table, "observe_batch", "observe", items=lambda a: len(a[0]))
+    tracer = None
+    if trace or program_layers:
+        from repro.serve.obs import Observability, Tracer
+
+        # enabled exactly while the spans are on, that is over the window
+        tracer = Tracer(sample=0.0, enabled=False)
+        Observability(tracer=tracer).attach(rt)
     if fault is not None:
         fault(rt)
 
@@ -400,12 +415,16 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool, *,
             rt.poll(now)
 
     spans.on = True
+    if tracer is not None:
+        tracer.enabled = True
     setup_s = time.time() - t_ready
     with spans.span("window"):
         i, returned, calls_lo, calls_t, wall0 = drive(
             due, tap.n_prefill, block, seconds, t0, ingest, poll, note_resolved)
     window_s = float(seconds)
     spans.on = False
+    if tracer is not None:
+        tracer.enabled = False
     if trace:
         jax.profiler.stop_trace()
     # the pending window and the ready queue resolve at the window's end
@@ -433,7 +452,8 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool, *,
         [np.full(rc.n_real, st) for rc, st in zip(rs, stamp[rec0:])], t0, window_s)
     r.spans = {k: list(v) for k, v in spans.total.items()}
     r.submits = spans.submits
-    r.shape = work.Shape.of(cfg)
+    r.shape = work.Shape.of(cfg, forest[1])
+    r.program = tracer.layers() if tracer is not None else None
     r.kernel_match = tuple(cfg["kernel_match"])
     r.chips = int(w["chips"])
     if trace:
@@ -502,7 +522,7 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool, *,
     if trace and keep_trace:
         save_fixture(keep_trace, r, spec)
     out["checks"] = checks
-    return out
+    return out, r
 
 
 def check(rt, captured, tap, pool, forest, feats, cfg, n_ingested, control):

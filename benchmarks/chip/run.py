@@ -41,9 +41,9 @@ def main(argv=None) -> int:
               file=sys.stderr)
         return 2
     try:
-        out = harness.run(a.workload, a.seed, a.seconds, bool(a.trace),
-                          pps=a.pps, control=bool(a.control), keep_trace=a.keep_trace,
-                          log=lambda s: print(s, file=sys.stderr, flush=True))
+        out, _ = harness.run(a.workload, a.seed, a.seconds, bool(a.trace),
+                             pps=a.pps, control=bool(a.control), keep_trace=a.keep_trace,
+                             log=lambda s: print(s, file=sys.stderr, flush=True))
     except harness.NoChip as e:
         print(f"run.py: {e}; no result", file=sys.stderr)
         return 3

@@ -16,6 +16,7 @@ import harness
 import reference
 
 CELL = "iot-uniform-sat"
+CELLS = ("iot-uniform-sat",)
 SEED = 2 ** 31 + 99
 SMALL = {"pool_flows": 1500, "train_flows": 2000, "prefill_s": 2.0}
 _PIPES: dict = {}
@@ -60,17 +61,18 @@ def _reference_pipeline(feats, forest, cfg, *, bf16):
     return dataclasses.replace(_xla_pipeline(feats, forest, cfg), _fn=serve)
 
 
-def _run(fault=None, control=False, make_pipeline=_xla_pipeline):
-    return harness.run(CELL, SEED, 1.5, False, pps=4000.0, require_tpu=False,
+def _run(cell, fault=None, control=False, make_pipeline=_xla_pipeline):
+    return harness.run(cell, SEED, 1.5, False, pps=4000.0, require_tpu=False,
                        control=control, make_pipeline=make_pipeline, fault=fault,
-                       log=lambda s: None, overrides=SMALL)
+                       log=lambda s: None, overrides=SMALL)[0]
 
 
-def test_reference_agrees_with_the_program_and_the_control_does_not():
+@pytest.mark.parametrize("cell", CELLS)
+def test_reference_agrees_with_the_program_and_the_control_does_not(cell):
     from repro.traffic.extraction import extract_features
     from repro.traffic.synth import TrafficDataset
 
-    spec = harness.load_cell(CELL)
+    spec = harness.load_cell(cell)
     cfg = dict(spec["config"], **{k: v for k, v in SMALL.items() if k in spec["config"]})
     feats, pool, forest = harness.build_forest(cfg, SEED)
     P = int(cfg["packet_depth"])
@@ -97,8 +99,9 @@ def test_reference_agrees_with_the_program_and_the_control_does_not():
     assert bad["prob_gap"] > 3 * limits["prob_gap"]
 
 
-def test_sound_run_is_correct_and_its_control_is_not():
-    out = _run(control=True)
+@pytest.mark.parametrize("cell", CELLS)
+def test_sound_run_is_correct_and_its_control_is_not(cell):
+    out = _run(cell, control=True)
     assert out["correct"] is True
     assert out["attempted"] > 100 and out["failed"] == 0
     assert list(out)[-1] == "checks"
@@ -107,9 +110,10 @@ def test_sound_run_is_correct_and_its_control_is_not():
     assert out["control"]["prob_gap"] > lim
 
 
+@pytest.mark.parametrize("cell", CELLS)
 @pytest.mark.parametrize("bf16", [False, True], ids=["float64", "bfloat16_control"])
-def test_reference_served_in_the_program_place_is_judged_by_its_precision(bf16):
-    out = _run(make_pipeline=lambda *a: _reference_pipeline(*a, bf16=bf16))
+def test_reference_served_in_the_program_place_is_judged_by_its_precision(bf16, cell):
+    out = _run(cell, make_pipeline=lambda *a: _reference_pipeline(*a, bf16=bf16))
     assert out["attempted"] > 100
     assert out["correct"] is (not bf16)
     if bf16:
@@ -144,10 +148,11 @@ def _altered_answer(rt):
     pipe.finalize = broken
 
 
+@pytest.mark.parametrize("cell", CELLS)
 @pytest.mark.parametrize("fault", [_half_batch, _altered_answer],
                          ids=["half_batch_left_out", "answer_altered"])
-def test_broken_timed_path_is_not_correct(fault):
-    out = _run(fault=fault)
+def test_broken_timed_path_is_not_correct(fault, cell):
+    out = _run(cell, fault=fault)
     assert out["correct"] is False
     assert out["failed"] > 0
 

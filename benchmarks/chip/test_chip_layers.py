@@ -1,19 +1,29 @@
-"""The program's layer spans, read through `layer_probe`: on a run of the
-cell (on the CPU, as in ``test_chip_control.py``) they count the same calls
-as the harness's wraps around them and time them alike, and the probe's
-reducers give hand-computed numbers on synthetic input.
+"""The program's layer spans, read through `layer_probe` and the metric
+readers: on a run of the cell (on the CPU, as in ``test_chip_control.py``)
+they count the same calls as the harness's wraps around them and time them
+alike, only a traced run keeps their table, and the reducers give
+hand-computed numbers on synthetic input.
 """
 import pytest
 
+import harness
 import layer_probe
+import program
 from test_chip_control import CELL, SEED, SMALL, _xla_pipeline
+
+RUN = dict(pps=4000.0, require_tpu=False, make_pipeline=_xla_pipeline,
+           log=lambda s: None, overrides=SMALL)
+PROGRAM_METRICS = ("observe_slow_time_pct", "dispatch_ns_per_pkt")
 
 
 @pytest.fixture(scope="module")
 def probed():
-    return layer_probe.probe(CELL, SEED, 1.5, True, pps=4000.0, require_tpu=False,
-                             make_pipeline=_xla_pipeline, log=lambda s: None,
-                             overrides=SMALL)
+    return layer_probe.probe(CELL, SEED, 1.5, True, **RUN)
+
+
+@pytest.fixture(scope="module")
+def untraced():
+    return harness.run(CELL, SEED, 1.5, False, **RUN)
 
 
 @pytest.mark.parametrize("name", ["observe", "submit"])
@@ -42,6 +52,23 @@ def test_probe_reports_the_window_alone(probed):
     assert set(doc["idle_by_span"]) <= set(layer_probe.LAYER_ORDER) | {"generator"}
 
 
+@pytest.mark.parametrize("name", PROGRAM_METRICS)
+def test_traced_run_reads_the_program_table(probed, name):
+    out, doc = probed
+    assert out["metrics"][name] == {"value": doc["quantities"][name],
+                                    "unit": "%" if name.endswith("_pct") else "ns"}
+    assert doc["quantities"][name] > 0
+
+
+@pytest.mark.parametrize("name", PROGRAM_METRICS)
+def test_untraced_run_attaches_no_tracer(untraced, name):
+    out, r = untraced
+    assert out["correct"] is True
+    assert r.program is None and r.trace is None
+    assert harness.metric_reader(name)(r) is None
+    assert set(out["metrics"]) == {"setup_s", "pps"}
+
+
 def test_quantities_by_hand():
     def row(calls, items, total, self_):
         return {"calls": calls, "items": items, "total_ns": total, "self_ns": self_}
@@ -54,12 +81,12 @@ def test_quantities_by_hand():
         "flush": row(5, 1280, 400_000, 100_000),
         "poll": row(3, 0, 60_000, 20_000),
     }, "counters": {}}
-    q = layer_probe.quantities(lay)
+    q = program.quantities(lay)
     assert q["pkts_per_observe"] == 40.0
     assert q["slow_path_pct"] == 5.0
     assert q["observe_slow_time_pct"] == 25.0
     assert q["dispatch_ns_per_pkt"] == (1_000_000 + 300_000 + 100_000 + 20_000) / 4000
-    assert layer_probe.quantities({"spans": {}, "counters": {}}) == {}
+    assert program.quantities({"spans": {}, "counters": {}}) == {}
 
 
 def test_idle_is_charged_to_the_innermost_program_span():

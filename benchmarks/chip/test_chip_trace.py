@@ -3,8 +3,10 @@ beside it.
 
 ``fixtures/trace_v5e.json`` is 100 ms of the traced window of an
 ``iot-uniform-sat`` run on one TPU v5 lite chip, normalised by
-`tracefile.load` and trimmed by `tracefile.trim`, with the submits of that
-span and what the metric readers returned from it when it was recorded.
+`tracefile.load` (the program's ``cato.*`` spans under ``program``) and
+trimmed by `tracefile.trim`, with the forest's shape as `work.Shape` counts
+it, the submits of that span and what the metric readers returned from it
+when it was recorded.
 """
 import importlib.util
 import json
@@ -74,3 +76,19 @@ def test_idle_is_charged_to_the_innermost_host_span():
     assert tracefile.busy_ns(ops, 0, 100) == 15
     assert tracefile.is_kernel("%fused_forest_infer.1", ["%fused_forest_infer"])
     assert not tracefile.is_kernel("%copy.31", ["%fused_forest_infer"])
+
+
+def test_program_spans_share_the_profiler_clock():
+    # the program's cato.* spans were kept beside the harness's own, and
+    # the device's idle time of the window is charged to them in full
+    import layer_probe
+
+    lo, hi = DOC["window"]
+    ops, prog = DOC["trace"]["device"][0], DOC["trace"]["program"]
+    names = {n for n, _, _ in prog}
+    assert {"cato.observe", "cato.ingest", "cato.submit"} <= names
+    assert all(n.startswith(tracefile.PROGRAM_PREFIX) for n in names)
+    idle = layer_probe.idle_by_span(ops, prog, lo, hi)
+    assert set(idle) <= set(layer_probe.LAYER_ORDER) | {"generator"}
+    assert sum(idle.values()) == pytest.approx(
+        (hi - lo - tracefile.busy_ns(ops, lo, hi)) / 1e9, rel=1e-9)

@@ -5,14 +5,17 @@ A captured trace is first normalised to plain lists, so that the reducers
 run the same on a trace just recorded and on the small recorded fixture the
 tests read:
 
-    {"device": [[[name, start_ns, dur_ns], ...] per chip],
-     "host":   [[name, start_ns, dur_ns], ...]}
+    {"device":  [[[name, start_ns, dur_ns], ...] per chip],
+     "host":    [[name, start_ns, dur_ns], ...],
+     "program": [[name, start_ns, dur_ns], ...]}
 
 Device events are those of the ``XLA Ops`` line of each ``/device:TPU:n``
 plane, named by their HLO instruction (``%fused_forest_infer.1``: the event
 names hold the whole instruction text, of which the name is the part before
 `` = ``); host events are the benchmark's own ``TraceAnnotation`` spans
-(names starting ``bench.``). Both carry the profiler's own clock.
+(names starting ``bench.``), program events the program's layer spans
+(``cato.``, while its tracer is attached). All carry the profiler's own
+clock.
 """
 from __future__ import annotations
 
@@ -22,6 +25,7 @@ import os
 import numpy as np
 
 SPAN_PREFIX = "bench."
+PROGRAM_PREFIX = "cato."
 # what the host was doing, most specific first: a time point inside several
 # spans is charged to the first of them in this order
 HOST_ORDER = ("submit", "resolve", "gather", "observe", "poll", "ingest")
@@ -44,7 +48,7 @@ def load(log_dir: str) -> dict:
     if not paths:
         raise FileNotFoundError(f"no .xplane.pb under {log_dir}")
     pd = jax.profiler.ProfileData.from_file(paths[0])
-    device, host = [], []
+    device, host, program = [], [], []
     for plane in pd.planes:
         if plane.name.startswith("/device:TPU:"):
             ops = []
@@ -55,9 +59,14 @@ def load(log_dir: str) -> dict:
             device.append(sorted(ops, key=lambda e: e[1]))
         elif plane.name.startswith("/host:"):
             for line in plane.lines:
-                host += [[e.name, int(e.start_ns), int(e.duration_ns)]
-                         for e in line.events if e.name.startswith(SPAN_PREFIX)]
-    return {"device": device, "host": sorted(host, key=lambda e: e[1])}
+                for e in line.events:
+                    ev = [e.name, int(e.start_ns), int(e.duration_ns)]
+                    if e.name.startswith(SPAN_PREFIX):
+                        host.append(ev)
+                    elif e.name.startswith(PROGRAM_PREFIX):
+                        program.append(ev)
+    return {"device": device, "host": sorted(host, key=lambda e: e[1]),
+            "program": sorted(program, key=lambda e: e[1])}
 
 
 def window(trace: dict) -> tuple[int, int]:
@@ -167,4 +176,5 @@ def trim(trace: dict, lo: int, hi: int) -> dict:
         return [e for e in evs if e[1] < hi and e[1] + e[2] > lo
                 and e[0] != SPAN_PREFIX + "window"]
     return {"device": [keep(ops) for ops in trace["device"]],
-            "host": [[SPAN_PREFIX + "window", lo, hi - lo]] + keep(trace["host"])}
+            "host": [[SPAN_PREFIX + "window", lo, hi - lo]] + keep(trace["host"]),
+            "program": keep(trace["program"])}
