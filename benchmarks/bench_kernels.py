@@ -34,14 +34,13 @@ def run(verbose=True):
     rows.append(("decode_attention_512", _time(
         lambda: ops.decode_attention(qd, kc, kc, lens))))
 
-    from repro.core.forest import train_forest
+    from repro.core.forest import CompactForest, train_forest
     X = R.standard_normal((512, 16)).astype(np.float32)
     y = R.integers(0, 4, 512)
-    f = train_forest(X, y, n_trees=16, max_depth=6)
-    fa = (jnp.asarray(X), jnp.asarray(f.feature), jnp.asarray(f.threshold),
-          jnp.asarray(f.leaf))
+    f = CompactForest.from_dense(train_forest(X, y, n_trees=16, max_depth=6))
+    fa = (jnp.asarray(X), jnp.asarray(f.nodes), jnp.asarray(f.leaf))
     rows.append(("forest_infer_512x16", _time(
-        lambda: ops.forest_infer(*fa, f.depth))))
+        lambda: ops.forest_infer(*fa, f.widths))))
 
     v = jnp.asarray(R.standard_normal((1024, 128)), jnp.float32)
     m = jnp.asarray(R.random((1024, 128)) < 0.5)

@@ -74,6 +74,7 @@ def run(device_label: str) -> list[str]:
     import jax
 
     from repro.core import FeatureRep
+    from repro.core.forest import CompactForest
     from repro.kernels.fused_pipeline import fused_forest_infer
     from repro.serve import (FlowTable, PacketStream, StreamingRuntime,
                              build_multi_tenant_pipeline)
@@ -152,12 +153,13 @@ def run(device_label: str) -> list[str]:
 
     # -- the fused entry is a Mosaic kernel, not an interpreter -----------
     ex = view.take(np.arange(MAX_BATCH))
+    compact = CompactForest.from_dense(forest)
     text = fused_forest_infer.lower(
         ex.ts, ex.size, ex.direction, ex.ttl, ex.winsize, ex.flags,
         ex.flow_len, ex.proto, ex.s_port, ex.d_port,
-        forest.feature, forest.threshold, forest.leaf,
+        compact.nodes, compact.leaf,
         plan=stats_plan(rep.features), depth=DEPTH,
-        forest_depth=forest.depth).compile().as_text()
+        widths=compact.widths).compile().as_text()
     has_kernel = "tpu_custom_call" in text
     print(f"fused entry compiled with tpu_custom_call: {has_kernel}")
     if not has_kernel:

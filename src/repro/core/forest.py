@@ -12,13 +12,17 @@ level-wise (breadth-first) greedy splitting on quantile-binned features,
 vectorized with ``np.bincount`` over (node, feature, bin) keys — the
 LightGBM-style histogram algorithm.
 
-Trees are stored in a *dense complete level-order layout*: a tree of
-``max_depth`` D is a perfect binary tree with ``2**D - 1`` internal slots and
-``2**D`` leaf slots. Traversal is pure index arithmetic —
-``node <- 2*node + 1 + (x[feat] > thresh)`` — with no pointer chasing, which
-is exactly the representation the TPU Pallas kernel (`repro.kernels.tree_infer`)
-consumes. Unused internal slots are pass-through (feature 0, threshold +inf:
+Trees are trained and exchanged in a *dense complete level-order layout*
+(`DenseForest`): a tree of ``max_depth`` D is a perfect binary tree with
+``2**D - 1`` internal slots and ``2**D`` leaf slots. Traversal is pure index
+arithmetic — ``node <- 2*node + 1 + (x[feat] > thresh)`` — with no pointer
+chasing. Unused internal slots are pass-through (feature 0, threshold +inf:
 always branch left); unused leaves replicate their parent's prediction.
+
+The TPU kernels (`repro.kernels.tree_infer`) serve a `CompactForest`
+instead, built on the host from a `DenseForest` or from CART node arrays:
+only the trees' real nodes, one window of slots per level, so a forest's
+size and traversal work follow its nodes and not ``2**D``.
 """
 from __future__ import annotations
 
@@ -28,7 +32,9 @@ from typing import Optional
 import numpy as np
 
 __all__ = [
+    "CompactForest",
     "DenseForest",
+    "compact_apply_np",
     "train_tree",
     "train_forest",
     "forest_apply_np",
@@ -75,6 +81,226 @@ class DenseForest:
             np.add.at(imp, f, 1.0)
         s = imp.sum()
         return imp / s if s > 0 else imp
+
+
+# ---------------------------------------------------------------------------
+# Compact (real-node) layout
+# ---------------------------------------------------------------------------
+
+LANES = 128      # a level's window and the leaf table round up to this
+FEATURE_BITS = 8  # a node code's low bits: the feature id
+MAX_CODE = 2 ** 24  # integers a float32 holds exactly
+
+
+def _round_lanes(n: int) -> int:
+    return max(LANES, -(-int(n) // LANES) * LANES)
+
+
+@dataclasses.dataclass(frozen=True)
+class CompactForest:
+    """A forest by its real nodes, in the layout the TPU kernels read.
+
+    Level d of every tree gets a window of ``widths[d]`` node slots (the
+    most real nodes any tree has on that level, rounded up to 128 lanes);
+    the windows lie side by side on the last axis of `nodes`. A slot holds
+    two float32 fields:
+
+    - ``nodes[t, 0, s]``: the threshold of an internal node (``x <= t``
+      goes left); +inf in a leaf slot, so no flow turns right there;
+    - ``nodes[t, 1, s]``: a code, ``256 * next + feature id``, exact in
+      float32 (`MAX_CODE`). For an internal node ``next`` is the slot of
+      its left child within level d+1's window (the right child is the
+      next slot); for a leaf it is ``-1 - leaf id``, and a flow that
+      reaches a leaf keeps that code through the remaining levels.
+
+    Level ``depth`` (the deepest) holds only leaves and has no window: a
+    tree's leaves there are numbered first, by slot, so the slot a flow
+    ends on is its leaf id. `leaf` is ``(T, K, L)``: tree t's leaf
+    distributions on the lanes, L the most leaves of any tree rounded up
+    to 128. Slots and leaves past a tree's own are zero and never read.
+    """
+
+    nodes: np.ndarray            # (T, 2, sum(widths)) float32
+    leaf: np.ndarray             # (T, K, L) float32
+    widths: tuple                # node slots per level, multiples of 128
+    n_internal: int              # real internal nodes over all trees
+    classes: Optional[np.ndarray] = None
+
+    @property
+    def n_trees(self) -> int:
+        return self.nodes.shape[0]
+
+    @property
+    def depth(self) -> int:
+        return len(self.widths)
+
+    @property
+    def n_out(self) -> int:
+        return self.leaf.shape[1]
+
+    @property
+    def lanes(self) -> int:
+        """Node slots a traversal's one-hots read for one flow, over every
+        tree and level."""
+        return self.n_trees * sum(self.widths)
+
+    @property
+    def nbytes(self) -> int:
+        return self.nodes.nbytes + self.leaf.nbytes
+
+    @classmethod
+    def from_dense(cls, forest: DenseForest) -> "CompactForest":
+        """The real nodes of a dense forest. A pass-through slot
+        (threshold +inf) always goes left, so it is replaced by its left
+        subtree; what the dense traversal reaches at the last level is a
+        leaf. Walks give the dense forest's results exactly."""
+        trees = [_dense_tree_nodes(forest.feature[t], forest.threshold[t],
+                                   forest.leaf[t], int(forest.depth))
+                 for t in range(forest.n_trees)]
+        return cls._build(trees, forest.n_out, forest.classes)
+
+    @classmethod
+    def from_cart(cls, trees, n_out: int, columns=None,
+                  classes=None) -> "CompactForest":
+        """A forest from CART node arrays, one object per tree with
+        scikit-learn's ``tree_`` fields: ``children_left`` and
+        ``children_right`` (-1 at a leaf), ``feature``, ``threshold``
+        (float64, ``x <= t`` goes left) and ``value`` (per node, the class
+        counts or fractions, shape ``(n_nodes, 1, k)`` or ``(n_nodes, k)``).
+        A leaf's distribution is its normalised value, written to the
+        output lanes `columns` (default ``0..k-1``); a threshold becomes
+        the largest float32 at or below it, so a float32 feature goes left
+        exactly when it does against the float64 one."""
+        out = []
+        for tr in trees:
+            left = np.asarray(tr.children_left, np.int64)
+            value = np.asarray(tr.value, np.float64).reshape(left.size, -1)
+            value = value / np.maximum(value.sum(axis=1, keepdims=True), 1e-300)
+            val = np.zeros((left.size, n_out), np.float32)
+            val[:, np.arange(value.shape[1]) if columns is None else columns] = value
+            t64 = np.asarray(tr.threshold, np.float64)
+            t32 = t64.astype(np.float32)
+            up = t32.astype(np.float64) > t64
+            t32[up] = np.nextafter(t32[up], np.float32(-np.inf))
+            inner = left >= 0
+            out.append((left, np.asarray(tr.children_right, np.int64),
+                        np.where(inner, tr.feature, 0),
+                        np.where(inner, t32, np.float32(np.inf)), val))
+        return cls._build(out, n_out, classes)
+
+    @classmethod
+    def _build(cls, trees, n_out: int, classes) -> "CompactForest":
+        """Lay out trees given as (left, right, feature, threshold, value)
+        node arrays, node 0 the root and -1 for a leaf's children."""
+        levels = []
+        for left, right, *_ in trees:
+            lv = [np.zeros(1, np.int64)]
+            while True:
+                inner = lv[-1][left[lv[-1]] >= 0]
+                if not inner.size:
+                    break
+                kids = np.empty(2 * inner.size, np.int64)
+                kids[0::2], kids[1::2] = left[inner], right[inner]
+                lv.append(kids)
+            levels.append(lv)
+        D = max(1, max(len(lv) - 1 for lv in levels))
+        widths = tuple(_round_lanes(max(lv[d].size for lv in levels if d < len(lv)))
+                       for d in range(D))
+        n_leaf = [sum(int((l[x] < 0).sum()) for x in lv)
+                  for (l, *_), lv in zip(trees, levels)]
+        offs = np.concatenate([[0], np.cumsum(widths)])
+        L = _round_lanes(max(n_leaf))
+        F = 1 + max((int(f[l >= 0].max(initial=0)) for l, _, f, *_ in trees))
+        if F > 2 ** FEATURE_BITS or max(max(widths), L) << FEATURE_BITS > MAX_CODE:
+            raise ValueError(f"forest too large for its node codes: {F} features, "
+                             f"{max(widths)} slots on a level, {L} leaves")
+        nodes = np.zeros((len(trees), 2, int(offs[-1])), np.float32)
+        leaf = np.zeros((len(trees), n_out, L), np.float32)
+        n_internal = 0
+        for t, ((left, _, feat, thr, val), lv) in enumerate(zip(trees, levels)):
+            # leaves of level D take ids 0.. by slot, the others follow
+            nxt = lv[D].size if len(lv) > D else 0
+            if nxt:
+                leaf[t, :, :nxt] = val[lv[D]].T
+            for d in range(min(D, len(lv))):
+                cur = lv[d]
+                inner = left[cur] >= 0
+                k = int(inner.sum())
+                ids = nxt + np.arange(cur.size - k)
+                leaf[t, :, ids] = val[cur[~inner]]
+                nxt += ids.size
+                n_internal += k
+                code = np.empty(cur.size, np.int64)
+                code[inner] = (2 * np.arange(k) << FEATURE_BITS) + feat[cur[inner]]
+                code[~inner] = (-1 - ids) << FEATURE_BITS
+                sl = slice(int(offs[d]), int(offs[d]) + cur.size)
+                nodes[t, 0, sl] = np.where(inner, thr[cur], np.inf)
+                nodes[t, 1, sl] = code
+        return cls(nodes, leaf, widths, n_internal, classes)
+
+
+def _pass_through(thr, g, lvl, depth):
+    """Follow pass-through slots (threshold +inf: always left) down their
+    left children, to the first slot on each path that splits or to its
+    leaf slot on level `depth`."""
+    while True:
+        inner = np.flatnonzero(lvl < depth)
+        skip = inner[thr[g[inner]] == np.inf]
+        if not skip.size:
+            return g, lvl
+        g[skip] = 2 * g[skip] + 1
+        lvl[skip] += 1
+
+
+def _dense_tree_nodes(feat, thr, leaf, depth):
+    """One dense tree's real nodes as breadth-first CART node arrays
+    ``(left, right, feature, threshold, value)``, -1 for a leaf's
+    children; a leaf's value is the dense leaf its path reaches."""
+    left, right, fid, th, val = [], [], [], [], []
+    g, lvl = _pass_through(thr, np.zeros(1, np.int64), np.zeros(1, np.int64), depth)
+    n = 0
+    while g.size:
+        inner = lvl < depth
+        k = int(inner.sum())
+        ids = n + g.size + 2 * np.arange(k)    # the next level's ids
+        lc = np.full(g.size, -1, np.int64)
+        rc = lc.copy()
+        lc[inner], rc[inner] = ids, ids + 1
+        left.append(lc)
+        right.append(rc)
+        slot = np.where(inner, g, 0)
+        fid.append(np.where(inner, feat[slot], 0))
+        th.append(np.where(inner, thr[slot], np.float32(np.inf)))
+        v = np.zeros((g.size, leaf.shape[-1]), np.float32)
+        v[~inner] = leaf[g[~inner] - (2 ** depth - 1)]
+        val.append(v)
+        n += g.size
+        kids = np.empty(2 * k, np.int64)
+        kids[0::2], kids[1::2] = 2 * g[inner] + 1, 2 * g[inner] + 2
+        g, lvl = _pass_through(thr, kids, np.repeat(lvl[inner] + 1, 2), depth)
+    return tuple(np.concatenate(a) for a in (left, right, fid, th, val))
+
+
+def compact_apply_np(forest: CompactForest, X: np.ndarray) -> np.ndarray:
+    """Average leaf payload across trees, walking the compact layout as the
+    kernels do. Returns (n, n_out); equals `forest_apply_np` on the dense
+    forest it was compacted from."""
+    X = np.asarray(X, dtype=np.float32)
+    n = X.shape[0]
+    rows = np.arange(n)
+    offs = np.concatenate([[0], np.cumsum(forest.widths)]).astype(np.int64)
+    acc = np.zeros((n, forest.n_out), dtype=np.float64)
+    for t in range(forest.n_trees):
+        rec = forest.nodes[t]
+        state = np.zeros(n, np.int64)
+        for d in range(forest.depth):
+            live = np.flatnonzero(state >= 0)
+            s = offs[d] + state[live]
+            code = rec[1, s].astype(np.int64)
+            go = X[rows[live], code & (2 ** FEATURE_BITS - 1)] > rec[0, s]
+            state[live] = (code >> FEATURE_BITS) + go
+        acc += forest.leaf[t][:, np.where(state < 0, -1 - state, state)].T
+    return (acc / forest.n_trees).astype(np.float32)
 
 
 # ---------------------------------------------------------------------------

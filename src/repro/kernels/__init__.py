@@ -5,7 +5,7 @@ Kernels (each `<name>.py` is a `pl.pallas_call` + explicit BlockSpec tiling;
 
   flash_attention  fused online-softmax attention, GQA, causal block skip
   decode_attention single-token GQA decode over a dense KV cache
-  tree_infer       dense level-order random-forest inference (model stage)
+  tree_infer       real-node random-forest inference (model stage)
   feature_extract  masked segmented flow statistics (extraction stage)
   fused_pipeline   single-launch extract+infer over flow tiles (serving)
   mamba_scan       chunked SSD selective scan (SSM/hybrid archs, long ctx)

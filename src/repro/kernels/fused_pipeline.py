@@ -8,8 +8,9 @@ stages (DESIGN.md §7): the grid tiles the flow axis, each step loads one
 feature columns *in registers* via the shared emitter
 (`repro.traffic.extraction.emit_feature_columns`, specialized on the static
 stats plan — the paper's conditional compilation, now inside Pallas), and
-immediately runs the dense level-order forest traversal on the in-register
-feature tile. The feature matrix never touches HBM.
+immediately runs the real-node forest traversal
+(`repro.core.forest.CompactForest`) on the in-register feature tile. The
+feature matrix never touches HBM.
 
 The contract is the float32 reference (`build_pipeline(use_kernel=False)`):
 equal predicted classes and probabilities within 1e-5. Feature columns
@@ -25,7 +26,7 @@ micro-batches — together with the dispatcher's staging arenas this makes a
 flush allocation-free on the host and reuse-friendly on the device.
 
 Swap-safety (DESIGN.md §9.3): the jit cache keys on the static
-``(plan, depth, forest_depth, batch shape)`` tuple, so two pipeline
+``(plan, depth, widths, batch shape)`` tuple, so two pipeline
 configurations can serve *concurrently* — during a zero-downtime
 hot-swap the background-warmed replacement (`ServingPipeline.warm`)
 and the still-serving old pipeline never evict or alias each other's
@@ -35,12 +36,16 @@ rotate independently).
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.forest import FEATURE_BITS
 from repro.traffic.extraction import (
     emit_agg_features,
     emit_feature_columns,
@@ -49,7 +54,7 @@ from repro.traffic.extraction import (
 )
 
 from .ops import resolve_interpret
-from .tree_infer import forest_votes, kernel_layout
+from .tree_infer import forest_votes
 
 __all__ = ["fused_agg_infer", "fused_forest_infer", "fused_pipeline_call",
            "fused_multi_forest_infer", "fused_multi_forest_call",
@@ -77,17 +82,32 @@ def _packet_specs(bn, P):
         pl.BlockSpec((bn, 4), _tile)]
 
 
-def _forest_specs(feature, leaf):
-    """BlockSpecs that keep a whole `kernel_layout` forest resident."""
-    return [pl.BlockSpec(feature.shape, _whole3),
-            pl.BlockSpec(feature.shape, _whole3),
-            pl.BlockSpec(leaf.shape, _whole3)]
+# the compiler's default scoped VMEM on a v5e: room for the kernel's tiles
+# and temporaries, beside the resident forest
+_SCOPED_VMEM = 16 * 2 ** 20
+
+
+def _vmem_bytes(shape) -> int:
+    """Bytes of a float32 array in VMEM, whose last two axes are laid out
+    in (8, 128) tiles."""
+    *lead, s, lanes = shape
+    return math.prod(lead) * (-(-s // 8) * 8) * (-(-lanes // 128) * 128) * 4
+
+
+def _resident_forest(nodes, leaf):
+    """BlockSpecs that keep a whole `CompactForest` (``nodes``, ``leaf``)
+    resident in VMEM, and compiler params whose VMEM limit holds it
+    double-buffered beside the kernel's default scope: the limit follows
+    the forest's size."""
+    forest = _vmem_bytes(nodes.shape) + _vmem_bytes(leaf.shape)
+    return ([pl.BlockSpec(nodes.shape, _whole3), pl.BlockSpec(leaf.shape, _whole3)],
+            pltpu.CompilerParams(vmem_limit_bytes=_SCOPED_VMEM + 2 * forest))
 
 
 def _fused_kernel(
     ts_ref, size_ref, dir_ref, ttl_ref, win_ref, flags_ref, meta_ref,
-    f_ref, t_ref, l_ref, o_ref,
-    *, plan, depth: int, forest_depth: int,
+    n_ref, l_ref, o_ref,
+    *, plan, depth: int, widths: tuple,
 ):
     meta = meta_ref[...]        # (bn, 4) float32: flow_len, proto, s/d_port
     cols = emit_feature_columns(
@@ -98,14 +118,14 @@ def _fused_kernel(
         d_port=meta[:, 3], depth=depth,
     )
     x = jnp.stack(cols, axis=1)                 # (bn, F) — in VMEM only
-    n = f_ref.shape[0]
-    o_ref[...] = forest_votes(x, f_ref, t_ref, l_ref, t0=0, n_trees=n,
-                              depth=forest_depth) / n
+    n = n_ref.shape[0]
+    o_ref[...] = forest_votes(x, n_ref, l_ref, t0=0, n_trees=n,
+                              widths=widths) / n
 
 
 def _agg_kernel(
-    agg_ref, meta_ref, f_ref, t_ref, l_ref, o_ref,
-    *, plan, forest_depth: int,
+    agg_ref, meta_ref, n_ref, l_ref, o_ref,
+    *, plan, widths: tuple,
 ):
     """Incremental entry (DESIGN.md §12): feature columns from the compact
     per-flow aggregate block instead of the raw packet window — a
@@ -117,15 +137,15 @@ def _agg_kernel(
         plan, agg_ref[...], proto=meta[:, 0], s_port=meta[:, 1],
         d_port=meta[:, 2])
     x = jnp.stack(cols, axis=1)
-    n = f_ref.shape[0]
-    o_ref[...] = forest_votes(x, f_ref, t_ref, l_ref, t0=0, n_trees=n,
-                              depth=forest_depth) / n
+    n = n_ref.shape[0]
+    o_ref[...] = forest_votes(x, n_ref, l_ref, t0=0, n_trees=n,
+                              widths=widths) / n
 
 
 def fused_pipeline_call(
     ts, size, direction, ttl, winsize, flags, meta,
-    feature, threshold, leaf,
-    *, plan, depth: int, forest_depth: int,
+    nodes, leaf,
+    *, plan, depth: int, widths: tuple,
     block_n: int = 256, interpret: bool = False,
 ):
     """Raw pallas_call: one launch over flow tiles, features never hit HBM.
@@ -133,43 +153,45 @@ def fused_pipeline_call(
     Expects float32 packet tensors, int32 `direction`, `flags` as the
     ``(N, P)`` int32 `pack_flags` bit mask, and
     ``meta = [flow_len, proto, s_port, d_port]`` as
-    ``(N, 4)`` float32. Pads the flow axis to the block multiple; the
+    ``(N, 4)`` float32; `nodes`, `leaf` and `widths` are a
+    `CompactForest`'s. Pads the flow axis to the block multiple; the
     whole forest stays resident, so the tree axis needs no padding.
     """
     N, P = ts.shape
-    K = leaf.shape[2]
+    K = leaf.shape[1]
     bn = min(block_n, N)
     rem_n = (-N) % bn
     if rem_n:
         ts, size, direction, ttl, winsize, flags, meta = _pad_rows(
             rem_n, ts, size, direction, ttl, winsize, flags, meta)
-    feature, threshold, leaf = kernel_layout(feature, threshold, leaf)
     kern = functools.partial(
-        _fused_kernel, plan=plan, depth=depth, forest_depth=forest_depth)
+        _fused_kernel, plan=plan, depth=depth, widths=widths)
+    specs, params = _resident_forest(nodes, leaf)
     out = pl.pallas_call(
         kern,
         grid=((N + rem_n) // bn,),
-        in_specs=_packet_specs(bn, P) + _forest_specs(feature, leaf),
+        in_specs=_packet_specs(bn, P) + specs,
         out_specs=pl.BlockSpec((bn, K), _tile),
         out_shape=jax.ShapeDtypeStruct((N + rem_n, K), jnp.float32),
+        compiler_params=params,
         interpret=interpret,
         # the kernel's instruction name in a device trace
         # (%fused_forest_infer.N); unnamed, it would follow the jit wrapper
         name="fused_forest_infer",
-    )(ts, size, direction, ttl, winsize, flags, meta, feature, threshold, leaf)
+    )(ts, size, direction, ttl, winsize, flags, meta, nodes, leaf)
     return out[:N]
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=("plan", "depth", "forest_depth", "block_n", "interpret"),
+    static_argnames=("plan", "depth", "widths", "block_n", "interpret"),
     donate_argnums=(0, 1, 2, 3, 4, 5),
 )
 def fused_forest_infer(
     ts, size, direction, ttl, winsize, flags,
     flow_len, proto, s_port, d_port,
-    feature, threshold, leaf,
-    *, plan, depth: int, forest_depth: int,
+    nodes, leaf,
+    *, plan, depth: int, widths: tuple,
     block_n: int = 256, interpret: bool | None = None,
 ):
     """Jit'd fused pipeline entry: packets -> class probabilities, one launch.
@@ -186,8 +208,8 @@ def fused_forest_infer(
         [flow_len.astype(jnp.float32), proto, s_port, d_port], axis=1)
     return fused_pipeline_call(
         ts, size, direction.astype(jnp.float32), ttl, winsize,
-        pack_flags(flags), meta, feature, threshold, leaf,
-        plan=plan, depth=depth, forest_depth=forest_depth,
+        pack_flags(flags), meta, nodes, leaf,
+        plan=plan, depth=depth, widths=widths,
         block_n=block_n, interpret=resolve_interpret(interpret),
     )
 
@@ -207,42 +229,42 @@ def fused_forest_infer(
 
 
 def stack_multi_forests(forests, tenant_cols):
-    """Stack N tenants' forests into tenant-stacked node arrays.
+    """Stack N tenants' `CompactForest`s along the tree axis.
 
     Each forest's node feature ids are remapped through `tenant_cols[t]`
-    into merged-column space and its node axis is zero-padded to the fleet
-    maximum (padding nodes are never visited). Leaf tables are padded to
-    ``(T_t, NL_max, K_max)`` with tenant t's classes at lanes ``[0, K_t)``.
-    Returns ``(feature, threshold, leaf, tenants)`` where ``tenants`` is
-    the static per-tenant spec tuple
-    ``(offset, n_trees, forest_depth, k0, k0 + K_t)`` that the kernel
+    into merged-column space, and its slots, leaves and classes are
+    zero-padded to the fleet maximum (padding is never visited). Tenant t's
+    classes sit at leaf rows ``[0, K_t)``. Returns ``(nodes, leaf,
+    tenants)`` as host arrays, where ``tenants`` is the static per-tenant
+    spec tuple ``(offset, n_trees, widths, k0, k0 + K_t)`` that the kernel
     specializes on: tenant t's probabilities are output lanes
     ``[k0, k0 + K_t)``.
     """
-    ni_max = max(int(f.feature.shape[1]) for f in forests)
-    nl_max = max(int(f.leaf.shape[1]) for f in forests)
-    k_max = max(int(f.leaf.shape[2]) for f in forests)
-    feats, thrs, leafs, tenants = [], [], [], []
+    mask = 2 ** FEATURE_BITS - 1
+    s_max = max(int(f.nodes.shape[2]) for f in forests)
+    l_max = max(int(f.leaf.shape[2]) for f in forests)
+    k_max = max(f.n_out for f in forests)
+    nodes, leafs, tenants = [], [], []
     off = k0 = 0
     for f, cols in zip(forests, tenant_cols):
-        T, ni = f.feature.shape
-        nl, k = f.leaf.shape[1], f.leaf.shape[2]
-        remap = jnp.asarray(cols, jnp.int32)[jnp.asarray(f.feature, jnp.int32)]
-        feats.append(jnp.pad(remap, ((0, 0), (0, ni_max - ni))))
-        thrs.append(jnp.pad(jnp.asarray(f.threshold, jnp.float32),
-                            ((0, 0), (0, ni_max - ni))))
-        leafs.append(jnp.pad(jnp.asarray(f.leaf, jnp.float32),
-                             ((0, 0), (0, nl_max - nl), (0, k_max - k))))
-        tenants.append((off, int(T), int(f.depth), k0, k0 + int(k)))
-        off += int(T)
-        k0 += int(k)
-    return (jnp.concatenate(feats, axis=0), jnp.concatenate(thrs, axis=0),
-            jnp.concatenate(leafs, axis=0), tuple(tenants))
+        if max(cols) > mask:
+            raise ValueError(f"{max(cols) + 1} merged feature columns; a node "
+                             f"code holds {mask + 1}")
+        code = f.nodes[:, 1].astype(np.int64)
+        n = f.nodes.copy()
+        n[:, 1] = (code & ~mask) | np.asarray(cols, np.int64)[code & mask]
+        nodes.append(np.pad(n, ((0, 0), (0, 0), (0, s_max - n.shape[2]))))
+        leafs.append(np.pad(f.leaf, ((0, 0), (0, k_max - f.n_out),
+                                     (0, l_max - f.leaf.shape[2]))))
+        tenants.append((off, f.n_trees, tuple(f.widths), k0, k0 + f.n_out))
+        off += f.n_trees
+        k0 += f.n_out
+    return np.concatenate(nodes), np.concatenate(leafs), tuple(tenants)
 
 
 def _multi_kernel(
     ts_ref, size_ref, dir_ref, ttl_ref, win_ref, flags_ref, meta_ref,
-    f_ref, t_ref, l_ref, o_ref,
+    n_ref, l_ref, o_ref,
     *, merged, tenants,
 ):
     meta = meta_ref[...]        # (bn, 4) float32: flow_len, proto, s/d_port
@@ -261,9 +283,9 @@ def _multi_kernel(
     col = lax.broadcasted_iota(jnp.int32, (k_max, k_sum), 1)
     lane = lax.broadcasted_iota(jnp.int32, (1, k_sum), 1)
     n_trees = jnp.ones((1, k_sum), jnp.float32)
-    for off, n, fd, k0, k1 in tenants:
-        votes = forest_votes(x, f_ref, t_ref, l_ref, t0=off, n_trees=n,
-                             depth=fd)
+    for off, n, widths, k0, k1 in tenants:
+        votes = forest_votes(x, n_ref, l_ref, t0=off, n_trees=n,
+                             widths=widths)
         # one-hot (k_max, k_sum): vote lane k -> output lane k0 + k; exact
         # at HIGHEST (each output is one f32 vote sum plus zeros)
         place = (col == row + k0) & (row < k1 - k0)
@@ -276,13 +298,13 @@ def _multi_kernel(
 
 def fused_multi_forest_call(
     ts, size, direction, ttl, winsize, flags, meta,
-    feature, threshold, leaf,
+    nodes, leaf,
     *, merged, tenants,
     block_n: int = 256, interpret: bool = False,
 ):
     """Raw pallas_call: one launch, N tenants' prediction lanes.
 
-    `feature`/`threshold`/`leaf` are the tenant-stacked arrays from
+    `nodes`/`leaf` are the tenant-stacked arrays from
     `stack_multi_forests`; the output is ``(N, sum of per-tenant n_out)``
     with tenant t's probabilities in its contiguous lane slice. Flow-axis
     padding matches `fused_pipeline_call` (zero rows: every mask empty).
@@ -294,16 +316,17 @@ def fused_multi_forest_call(
     if rem_n:
         ts, size, direction, ttl, winsize, flags, meta = _pad_rows(
             rem_n, ts, size, direction, ttl, winsize, flags, meta)
-    feature, threshold, leaf = kernel_layout(feature, threshold, leaf)
     kern = functools.partial(_multi_kernel, merged=merged, tenants=tenants)
+    specs, params = _resident_forest(nodes, leaf)
     out = pl.pallas_call(
         kern,
         grid=((N + rem_n) // bn,),
-        in_specs=_packet_specs(bn, P) + _forest_specs(feature, leaf),
+        in_specs=_packet_specs(bn, P) + specs,
         out_specs=pl.BlockSpec((bn, k_sum), _tile),
         out_shape=jax.ShapeDtypeStruct((N + rem_n, k_sum), jnp.float32),
+        compiler_params=params,
         interpret=interpret,
-    )(ts, size, direction, ttl, winsize, flags, meta, feature, threshold, leaf)
+    )(ts, size, direction, ttl, winsize, flags, meta, nodes, leaf)
     return out[:N]
 
 
@@ -315,7 +338,7 @@ def fused_multi_forest_call(
 def fused_multi_forest_infer(
     ts, size, direction, ttl, winsize, flags,
     flow_len, proto, s_port, d_port,
-    feature, threshold, leaf,
+    nodes, leaf,
     *, merged, tenants,
     block_n: int = 256, interpret: bool | None = None,
 ):
@@ -328,15 +351,15 @@ def fused_multi_forest_infer(
         [flow_len.astype(jnp.float32), proto, s_port, d_port], axis=1)
     return fused_multi_forest_call(
         ts, size, direction.astype(jnp.float32), ttl, winsize,
-        pack_flags(flags), meta, feature, threshold, leaf,
+        pack_flags(flags), meta, nodes, leaf,
         merged=merged, tenants=tenants, block_n=block_n,
         interpret=resolve_interpret(interpret),
     )
 
 
 def fused_agg_call(
-    agg, meta, feature, threshold, leaf,
-    *, plan, forest_depth: int,
+    agg, meta, nodes, leaf,
+    *, plan, widths: tuple,
     block_n: int = 256, interpret: bool = False,
 ):
     """Raw pallas_call for the aggregate entry: one launch over flow tiles
@@ -345,36 +368,36 @@ def fused_agg_call(
     so the emitter's masked reductions yield a defined all-zero feature
     row), exactly as the window entry does."""
     N, W = agg.shape
-    K = leaf.shape[2]
+    K = leaf.shape[1]
     bn = min(block_n, N)
     rem_n = (-N) % bn
     if rem_n:
         agg, meta = _pad_rows(rem_n, agg, meta)
-    feature, threshold, leaf = kernel_layout(feature, threshold, leaf)
-    kern = functools.partial(_agg_kernel, plan=plan,
-                             forest_depth=forest_depth)
+    kern = functools.partial(_agg_kernel, plan=plan, widths=widths)
+    specs, params = _resident_forest(nodes, leaf)
     out = pl.pallas_call(
         kern,
         grid=((N + rem_n) // bn,),
         in_specs=[
             pl.BlockSpec((bn, W), _tile),           # aggregate block
             pl.BlockSpec((bn, 3), _tile),           # proto, s_port, d_port
-        ] + _forest_specs(feature, leaf),
+        ] + specs,
         out_specs=pl.BlockSpec((bn, K), _tile),
         out_shape=jax.ShapeDtypeStruct((N + rem_n, K), jnp.float32),
+        compiler_params=params,
         interpret=interpret,
-    )(agg, meta, feature, threshold, leaf)
+    )(agg, meta, nodes, leaf)
     return out[:N]
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=("plan", "forest_depth", "block_n", "interpret"),
+    static_argnames=("plan", "widths", "block_n", "interpret"),
 )
 def fused_agg_infer(
     agg, proto, s_port, d_port,
-    feature, threshold, leaf,
-    *, plan, forest_depth: int,
+    nodes, leaf,
+    *, plan, widths: tuple,
     block_n: int = 256, interpret: bool | None = None,
 ):
     """Jit'd incremental pipeline entry: aggregate rows -> class
@@ -384,7 +407,7 @@ def fused_agg_infer(
     """
     meta = jnp.stack([proto, s_port, d_port], axis=1)
     return fused_agg_call(
-        agg.astype(jnp.float32), meta, feature, threshold, leaf,
-        plan=plan, forest_depth=forest_depth,
+        agg.astype(jnp.float32), meta, nodes, leaf,
+        plan=plan, widths=widths,
         block_n=block_n, interpret=resolve_interpret(interpret),
     )

@@ -102,13 +102,15 @@ def decode_attention(q, k_cache, v_cache, lengths, *, scale=None, block_s=256,
     )
 
 
-@functools.partial(jax.jit, static_argnames=("depth", "block_n", "block_t", "interpret"))
-def forest_infer(x, feature, threshold, leaf, depth, *, block_n=256, block_t=8,
+@functools.partial(jax.jit, static_argnames=("widths", "block_n", "block_t", "interpret"))
+def forest_infer(x, nodes, leaf, widths, *, block_n=256, block_t=8,
                  interpret=None):
-    # flow/tree padding and pass-through trees live in the kernel call
+    """Mean leaf votes of a `CompactForest` (``nodes``, ``leaf``,
+    ``widths``) for each row of `x`; flow/tree padding lives in the kernel
+    call."""
     interpret = resolve_interpret(interpret)
     return forest_infer_kernel_call(
-        x, feature, threshold, leaf, depth,
+        x, nodes, leaf, widths,
         block_n=block_n, block_t=block_t, interpret=interpret,
     )
 

@@ -100,8 +100,11 @@ class PipelineSwap:
 
         if warm_buckets is None:
             warm_buckets = warm_buckets_for(runtime)
+        tracer = (None if runtime is None else
+                  getattr(runtime, "shards", [runtime])[0].dispatcher.tracer)
         pipeline = build_pipeline(rep, forest, max_pkts=rep.depth,
-                                  fused=fused, use_kernel=use_kernel)
+                                  fused=fused, use_kernel=use_kernel,
+                                  tracer=tracer)
         pipeline.warm(list(warm_buckets))
         if service is None:
             service = ServiceModel.modeled(rep, forest)

@@ -433,6 +433,8 @@ class MicroBatchDispatcher:
             if tr is not None:
                 with tr.layer("submit", _h2d_bytes(ds)):
                     rec.probs = self.pipeline.predict_async(ds)
+                for name, k in self.pipeline.counts.items():
+                    tr.count(name, k)
             else:
                 rec.probs = self.pipeline.predict_async(ds)
             self._pending.append(rec)

@@ -40,7 +40,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.forest import DenseForest
+from repro.core.forest import CompactForest, DenseForest
 from repro.core.search_space import FeatureRep, SearchSpace
 
 from .extraction import (
@@ -52,6 +52,7 @@ from .extraction import (
     stats_plan,
 )
 from .features import modeled_extraction_cost_ns
+from .pipeline import forest_counts, served_forest
 from .profiler import ProfileResult, TrafficProfiler
 from .synth import TrafficDataset
 
@@ -112,13 +113,15 @@ class MultiTenantPipeline:
 
     rep: FeatureRep                         # union features @ max depth
     tenant_reps: tuple[FeatureRep, ...]
-    forests: tuple[DenseForest, ...]
+    forests: tuple[DenseForest | CompactForest, ...]   # as given
     merged: tuple                           # merged plan: ((entry, depth), ...)
     tenant_cols: tuple[tuple[int, ...], ...]
     lanes: tuple[tuple[int, int], ...]      # per-tenant prob column spans
     _fn: Callable
     fused: bool = False
     _agg_fn: Optional[Callable] = None
+    # per-batch counters of a traced submit, as `ServingPipeline.counts`
+    counts: dict = dataclasses.field(default_factory=dict)
 
     @property
     def n_tenants(self) -> int:
@@ -189,7 +192,7 @@ class MultiTenantPipeline:
 
 def build_multi_tenant_pipeline(
     reps: Sequence[FeatureRep],
-    forests: Sequence[DenseForest],
+    forests: Sequence[DenseForest | CompactForest],
     *,
     use_kernel: bool = True,
     fused: bool = False,
@@ -201,7 +204,10 @@ def build_multi_tenant_pipeline(
     gathers per-tenant column subsets from the merged feature matrix and
     runs the solo forest kernel (`use_kernel=True`) or the jnp reference
     per tenant. The incremental (aggregate) entry always takes the
-    unfused route — refresh batches are low-rate (DESIGN.md §12)."""
+    unfused route — refresh batches are low-rate (DESIGN.md §12). The
+    kernels serve each forest's real nodes (`served_forest`: a
+    `CompactForest` as it is, a `DenseForest` compacted on the host); only
+    the jnp reference reads the dense tables, and needs `DenseForest`s."""
     reps = tuple(reps)
     forests = tuple(forests)
     if len(reps) != len(forests) or not reps:
@@ -211,28 +217,38 @@ def build_multi_tenant_pipeline(
     urep = union_rep(reps)
     lanes, k0 = [], 0
     for f in forests:
-        k = int(f.leaf.shape[2])
+        k = int(f.n_out)
         lanes.append((k0, k0 + k))
         k0 += k
 
     incremental = merged_plan_is_incremental(merged)
-    consts = [(jnp.asarray(f.feature), jnp.asarray(f.threshold),
-               jnp.asarray(f.leaf), int(f.depth)) for f in forests]
     col_idx = [np.asarray(c, np.int32) for c in tenant_cols]
+    counts = {}
+    if fused or use_kernel:
+        from repro.kernels import ops
+
+        compact = [served_forest(f) for f in forests]
+        counts = forest_counts(compact)
+        consts = [(jnp.asarray(c.nodes), jnp.asarray(c.leaf), c.widths)
+                  for c in compact]
+
+        def infer_one(x, c):
+            return ops.forest_infer(x, *c)
+    else:
+        from repro.kernels import ref
+
+        if not all(isinstance(f, DenseForest) for f in forests):
+            raise TypeError("the jnp reference (use_kernel=False) reads "
+                            "DenseForests' tables")
+        consts = [(jnp.asarray(f.feature), jnp.asarray(f.threshold),
+                   jnp.asarray(f.leaf), int(f.depth)) for f in forests]
+
+        def infer_one(x, c):
+            return ref.forest_infer_ref(x, *c)
 
     def infer_tenants(X):
-        outs = []
-        for idx, (ft, tt, lt, fd) in zip(col_idx, consts):
-            x = X[:, idx]
-            if use_kernel:
-                from repro.kernels import ops
-
-                outs.append(ops.forest_infer(x, ft, tt, lt, fd))
-            else:
-                from repro.kernels import ref
-
-                outs.append(ref.forest_infer_ref(x, ft, tt, lt, fd))
-        return jnp.concatenate(outs, axis=1)
+        return jnp.concatenate([infer_one(X[:, idx], c)
+                                for idx, c in zip(col_idx, consts)], axis=1)
 
     if fused:
         from repro.kernels.fused_pipeline import (
@@ -240,8 +256,9 @@ def build_multi_tenant_pipeline(
             stack_multi_forests,
         )
 
-        feat_all, thr_all, leaf_all, tenants_spec = stack_multi_forests(
-            forests, tenant_cols)
+        nodes_all, leaf_all, tenants_spec = stack_multi_forests(
+            compact, tenant_cols)
+        nodes_all, leaf_all = jnp.asarray(nodes_all), jnp.asarray(leaf_all)
 
         def run(ds: TrafficDataset):
             with warnings.catch_warnings():
@@ -252,7 +269,7 @@ def build_multi_tenant_pipeline(
                 return fused_multi_forest_infer(
                     ds.ts, ds.size, ds.direction, ds.ttl, ds.winsize,
                     ds.flags, ds.flow_len, ds.proto, ds.s_port, ds.d_port,
-                    feat_all, thr_all, leaf_all,
+                    nodes_all, leaf_all,
                     merged=merged, tenants=tenants_spec,
                 )
     else:
@@ -273,7 +290,7 @@ def build_multi_tenant_pipeline(
     return MultiTenantPipeline(
         rep=urep, tenant_reps=reps, forests=forests, merged=merged,
         tenant_cols=tenant_cols, lanes=tuple(lanes), _fn=run, fused=fused,
-        _agg_fn=run_agg,
+        _agg_fn=run_agg, counts=counts,
     )
 
 

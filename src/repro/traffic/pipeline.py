@@ -6,8 +6,12 @@ the Optimizer plus its trained model and returns a single compiled callable
     packets (dense flow tensors) -> class predictions
 
 containing exactly the extraction ops for (F, n) (jit specialization ==
-conditional compilation, DESIGN.md §3) fused with the dense-forest inference
-stage. Two fusion levels exist:
+conditional compilation, DESIGN.md §3) fused with the forest inference
+stage. The kernels serve the forest by its real nodes: `build_pipeline`
+takes a `CompactForest` as it is, or compacts a trained `DenseForest` on the
+host (`CompactForest.from_dense`) before anything reaches the device; only
+the jnp reference (``use_kernel=False``) reads a `DenseForest`'s tables,
+on the device. Two fusion levels exist:
 
 - ``fused=False`` (two launches): the jit-specialized XLA extraction
   executable materializes the ``(N, F)`` feature matrix, then the
@@ -35,14 +39,36 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.forest import DenseForest
+from repro.core.forest import CompactForest, DenseForest
 from repro.core.search_space import FeatureRep
 from repro.kernels import ops
 
 from .extraction import emit_agg_features, extraction_fn, stats_plan
 from .synth import TrafficDataset
 
-__all__ = ["ServingPipeline", "build_pipeline"]
+__all__ = ["ServingPipeline", "build_pipeline", "served_forest", "forest_counts"]
+
+
+def served_forest(forest: DenseForest | CompactForest, tracer=None) -> CompactForest:
+    """The layout the kernels read: a `CompactForest` as it is, a
+    `DenseForest` compacted (`CompactForest.from_dense`), timed as a
+    ``forest.compact`` layer span (items: trees) while `tracer` (an
+    `obs.Tracer`) is enabled."""
+    if isinstance(forest, CompactForest):
+        return forest
+    if tracer is not None and tracer.enabled:
+        with tracer.layer("forest.compact", forest.n_trees):
+            return CompactForest.from_dense(forest)
+    return CompactForest.from_dense(forest)
+
+
+def forest_counts(forests) -> dict:
+    """Counters a traced submit adds per batch: ``forest.nodes``, the
+    served forests' real internal nodes, and ``forest.lanes``, the node
+    slots the traversal's one-hots read for one flow over every tree and
+    level."""
+    return {"forest.nodes": sum(f.n_internal for f in forests),
+            "forest.lanes": sum(f.lanes for f in forests)}
 
 
 @functools.partial(jax.jit, static_argnames=("plan",))
@@ -58,10 +84,13 @@ def _agg_extract(agg, proto, s_port, d_port, *, plan):
 @dataclasses.dataclass
 class ServingPipeline:
     rep: FeatureRep
-    forest: DenseForest
+    forest: DenseForest | CompactForest     # as `build_pipeline` was given it
     _fn: Callable
     fused: bool = False
     _agg_fn: Optional[Callable] = None
+    # what a traced submit counts per batch (`forest_counts`); empty on the
+    # jnp reference path, which reads the dense tables
+    counts: dict = dataclasses.field(default_factory=dict)
 
     def __call__(self, ds: TrafficDataset) -> np.ndarray:
         """Predicted class ids for every flow in the batch."""
@@ -145,21 +174,42 @@ class ServingPipeline:
 
 def build_pipeline(
     rep: FeatureRep,
-    forest: DenseForest,
+    forest: DenseForest | CompactForest,
     max_pkts: int,
     *,
     use_kernel: bool = True,
     fused: bool = False,
+    tracer=None,
 ) -> ServingPipeline:
-    feat_t = jnp.asarray(forest.feature)
-    thr_t = jnp.asarray(forest.threshold)
-    leaf_t = jnp.asarray(forest.leaf)
-    depth = forest.depth
-
+    """The serving pipeline of `rep` and its trained `forest`, a
+    `CompactForest` or a `DenseForest` (`served_forest`; the jnp reference
+    needs the dense one); `tracer` (an enabled `obs.Tracer`, e.g. during a
+    hot swap) times a dense forest's compaction."""
     from .extraction import plan_is_incremental
 
     plan = stats_plan(rep.features)
     incremental = plan_is_incremental(plan)
+
+    counts = {}
+    if fused or use_kernel:
+        cf = served_forest(forest, tracer)
+        nodes_t, leaf_t, widths = (jnp.asarray(cf.nodes), jnp.asarray(cf.leaf),
+                                   cf.widths)
+        counts = forest_counts([cf])
+
+        def infer(x):
+            return ops.forest_infer(x, nodes_t, leaf_t, widths)
+    else:
+        from repro.kernels import ref
+
+        if not isinstance(forest, DenseForest):
+            raise TypeError("the jnp reference (use_kernel=False) reads a "
+                            "DenseForest's tables")
+        dense = [jnp.asarray(a)
+                 for a in (forest.feature, forest.threshold, forest.leaf)]
+
+        def infer(x):
+            return ref.forest_infer_ref(x, *dense, forest.depth)
 
     if fused:
         from repro.kernels.fused_pipeline import (
@@ -179,8 +229,8 @@ def build_pipeline(
                 return fused_forest_infer(
                     ds.ts, ds.size, ds.direction, ds.ttl, ds.winsize,
                     ds.flags, ds.flow_len, ds.proto, ds.s_port, ds.d_port,
-                    feat_t, thr_t, leaf_t,
-                    plan=plan, depth=conn_depth, forest_depth=depth,
+                    nodes_t, leaf_t,
+                    plan=plan, depth=conn_depth, widths=widths,
                 )
 
         run_agg = None
@@ -189,32 +239,23 @@ def build_pipeline(
                 return fused_agg_infer(
                     jnp.asarray(agg), jnp.asarray(proto),
                     jnp.asarray(s_port), jnp.asarray(d_port),
-                    feat_t, thr_t, leaf_t,
-                    plan=plan, forest_depth=depth,
+                    nodes_t, leaf_t,
+                    plan=plan, widths=widths,
                 )
 
-        return ServingPipeline(rep, forest, run, fused=True, _agg_fn=run_agg)
+        return ServingPipeline(rep, forest, run, fused=True, _agg_fn=run_agg,
+                               counts=counts)
 
     extract = extraction_fn(rep.features, rep.depth, max_pkts)
 
     def run(ds: TrafficDataset):
-        x = extract(ds)
-        if use_kernel:
-            return ops.forest_infer(x, feat_t, thr_t, leaf_t, depth)
-        from repro.kernels import ref
-
-        return ref.forest_infer_ref(x, feat_t, thr_t, leaf_t, depth)
+        return infer(extract(ds))
 
     run_agg = None
     if incremental:
         def run_agg(agg, proto, s_port, d_port):
-            x = _agg_extract(
+            return infer(_agg_extract(
                 jnp.asarray(agg), jnp.asarray(proto), jnp.asarray(s_port),
-                jnp.asarray(d_port), plan=plan)
-            if use_kernel:
-                return ops.forest_infer(x, feat_t, thr_t, leaf_t, depth)
-            from repro.kernels import ref
+                jnp.asarray(d_port), plan=plan))
 
-            return ref.forest_infer_ref(x, feat_t, thr_t, leaf_t, depth)
-
-    return ServingPipeline(rep, forest, run, _agg_fn=run_agg)
+    return ServingPipeline(rep, forest, run, _agg_fn=run_agg, counts=counts)

@@ -14,6 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core.forest import CompactForest, DenseForest
 from repro.core.search_space import FeatureRep
 from repro.kernels import ref
 from repro.kernels.feature_extract import flow_stats_kernel_call
@@ -138,9 +139,11 @@ def test_forest_kernel_pads_both_axes(n, T, bn, bt):
     threshold = jnp.asarray(R.standard_normal((T, 2 ** depth - 1)), jnp.float32)
     leaf = jnp.asarray(R.random((T, 2 ** depth, K)), jnp.float32)
     x = jnp.asarray(R.standard_normal((n, F)), jnp.float32)
+    cf = CompactForest.from_dense(DenseForest(
+        np.asarray(feature), np.asarray(threshold), np.asarray(leaf), depth, F))
     got = forest_infer_kernel_call(
-        x, feature, threshold, leaf, depth, block_n=bn, block_t=bt,
-        interpret=True)
+        x, jnp.asarray(cf.nodes), jnp.asarray(cf.leaf), cf.widths,
+        block_n=bn, block_t=bt, interpret=True)
     assert got.shape == (n, K)
     want = ref.forest_infer_ref(x, feature, threshold, leaf, depth)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)

@@ -3,7 +3,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core.forest import forest_apply_np, train_forest
+from repro.core.forest import (
+    CompactForest, DenseForest, forest_apply_np, train_forest,
+)
 from repro.kernels import ops, ref
 
 R = np.random.default_rng(0)
@@ -58,9 +60,10 @@ def test_forest_infer_sweep(n, F, K, T, depth):
     f = train_forest(X, y, n_trees=T, max_depth=depth,
                      rng=np.random.default_rng(1))
     want = forest_apply_np(f, X)
+    cf = CompactForest.from_dense(f)
     got = ops.forest_infer(
-        jnp.asarray(X), jnp.asarray(f.feature), jnp.asarray(f.threshold),
-        jnp.asarray(f.leaf), f.depth, block_n=128, block_t=4,
+        jnp.asarray(X), jnp.asarray(cf.nodes), jnp.asarray(cf.leaf),
+        cf.widths, block_n=128, block_t=4,
     )
     np.testing.assert_allclose(np.asarray(got), want, atol=1e-5)
     got_ref = ref.forest_infer_ref(
@@ -77,8 +80,8 @@ def test_forest_infer_sweep(n, F, K, T, depth):
 ])
 def test_forest_infer_ref_vs_kernel_random(n, F, K, T, depth, block_t):
     """Direct ref-vs-Pallas parity on *random* dense forests: arbitrary
-    feature ids, thresholds (incl. +inf pass-through slots) and leaves —
-    not just trainer-produced trees."""
+    feature ids, thresholds (incl. +inf pass-through slots, with real
+    splits below them) and leaves — not just trainer-produced trees."""
     rng = np.random.default_rng(n + T)
     n_int, n_leaf = 2 ** depth - 1, 2 ** depth
     feature = rng.integers(0, F, (T, n_int)).astype(np.int32)
@@ -86,9 +89,10 @@ def test_forest_infer_ref_vs_kernel_random(n, F, K, T, depth, block_t):
     threshold[rng.random((T, n_int)) < 0.15] = np.inf  # pass-through slots
     leaf = rng.random((T, n_leaf, K)).astype(np.float32)
     X = rng.standard_normal((n, F)).astype(np.float32)
+    cf = CompactForest.from_dense(DenseForest(feature, threshold, leaf, depth, F))
     got = ops.forest_infer(
-        jnp.asarray(X), jnp.asarray(feature), jnp.asarray(threshold),
-        jnp.asarray(leaf), depth, block_n=128, block_t=block_t,
+        jnp.asarray(X), jnp.asarray(cf.nodes), jnp.asarray(cf.leaf),
+        cf.widths, block_n=128, block_t=block_t,
     )
     want = ref.forest_infer_ref(
         jnp.asarray(X), jnp.asarray(feature), jnp.asarray(threshold),

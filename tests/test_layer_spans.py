@@ -3,6 +3,7 @@
 changes a result, and that the spans share the profiler's clock with
 annotations made around the program's calls.
 """
+import dataclasses
 import glob
 import os
 
@@ -212,6 +213,49 @@ def test_disabled_tracer_opens_no_span(pipeline, stream, monkeypatch):
         rt = _runtime(pipeline, tr, **kw)
         rt.drain(_ingest(rt, st, speed=speed)[0] + 1.0)
     assert tr.layers() == {"spans": {}, "counters": {}}
+
+
+def test_traced_submit_counts_the_served_forest(pipeline, stream):
+    """Every submit adds the pipeline's forest counters (real nodes and
+    one-hot lanes) while the tracer is enabled, and nothing while not."""
+    _, st = stream
+    counted = dataclasses.replace(pipeline, counts={"forest.nodes": 7,
+                                                    "forest.lanes": 640})
+    on, off = Tracer(sample=0.0), Tracer(sample=0.0, enabled=False)
+    for tr in (on, off):
+        rt = _runtime(counted, tr)
+        rt.drain(_ingest(rt, st)[0] + 1.0)
+    batches = on.layers()["spans"]["submit"]["calls"]
+    assert batches == len(rt.dispatcher.records) > 0
+    assert on.layers()["counters"]["forest.nodes"] == 7 * batches
+    assert on.layers()["counters"]["forest.lanes"] == 640 * batches
+    assert off.layers()["counters"] == {}
+
+
+def test_forest_compaction_is_a_span_while_enabled(pipeline, stream):
+    """A hot swap onto a traced runtime times the new forest's compaction
+    on the runtime's tracer; a disabled tracer records nothing, and a
+    forest that needs no compaction (already compact, or served by the jnp
+    reference from its dense tables) opens no span."""
+    from repro.core.forest import CompactForest
+    from repro.serve.control.plane import PipelineSwap
+
+    ds, _ = stream
+    rep = FeatureRep(("dur", "s_load", "ack_cnt"), depth=DEPTH)
+    forest, _ = train_traffic_model(extract_features(ds, rep.features, rep.depth),
+                                    ds.label, model="rf-fast", seed=0)
+    on, off = Tracer(sample=0.0), Tracer(sample=0.0, enabled=False)
+    for tr in (on, off):
+        PipelineSwap.build(rep, forest, runtime=_runtime(pipeline, tr),
+                           warm_buckets=(8,))
+    compact = CompactForest.from_dense(forest)
+    PipelineSwap.build(rep, compact, runtime=_runtime(pipeline, on),
+                       warm_buckets=())
+    build_pipeline(rep, forest, rep.depth, use_kernel=False, tracer=on)
+    row = on.layers()["spans"]["forest.compact"]
+    assert row["calls"] == 1 and row["items"] == forest.n_trees
+    assert row["total_ns"] > 0
+    assert off.layers()["spans"] == {}
 
 
 def test_lifecycles_close_once_at_resolve(pipeline, stream):
